@@ -24,6 +24,7 @@ from repro.configs import get_smoke
 from repro.core.p3sapp import p3sapp_dataset
 from repro.data.synthetic import write_corpus
 from repro.distributed.sharding import tree_shardings
+from repro.launch.env import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models.lm import LM, MeshContext
 from repro.optim.adamw import AdamW
@@ -38,6 +39,7 @@ def main() -> None:
     ap.add_argument("--batch", type=int, default=16)
     args = ap.parse_args()
 
+    enable_compile_cache()
     corpus = tempfile.mkdtemp(prefix="p3sapp_corpus_")
     write_corpus(corpus, total_bytes=2_000_000, n_files=4, seed=7)
     ds = p3sapp_dataset([corpus])

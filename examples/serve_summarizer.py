@@ -24,6 +24,7 @@ from repro.configs import get_smoke
 from repro.core.dataset import Dataset
 from repro.core.expr import abstract_expr, col
 from repro.data.batching import TokenSpec
+from repro.launch.env import enable_compile_cache
 from repro.models.lm import LM
 from repro.runtime.serve_loop import RingCache, ServeStats, TextRequest, serve_text
 
@@ -43,6 +44,7 @@ def main() -> None:
     ap.add_argument("--max-new", type=int, default=8)
     args = ap.parse_args()
 
+    enable_compile_cache()
     # 1. Fit the preprocessing plan + vocabulary on a tiny corpus, exactly
     # like training would, then lower it to a per-request row program.
     corpus_dir = Path(tempfile.mkdtemp(prefix="serve_corpus_")) / "shards"

@@ -2,10 +2,12 @@
 
 One declarative ``Dataset`` chain takes the synthetic CORE corpus all the
 way to device-resident batches — ingestion, pre-cleaning, the Spark-ML-style
-stage chain, tokenization, batching, and async prefetch are a single lazy
-plan the planner fuses and overlaps with device compute. The model side is
-an LSTM seq2seq with Bahdanau attention, checkpointed training
-(resume-capable), and greedy inference samples.
+stage chain, tokenization, bucketed batching and async prefetch are a
+single lazy plan whose shards stream through worker processes while the
+device trains. The model side is an LSTM seq2seq with Bahdanau attention,
+checkpointed training (resume-capable), and greedy inference samples on a
+held-out corpus. :func:`repro.runtime.summarizer.train_summarizer` is the
+whole training path; ``chip_smoke.py`` runs the same function.
 
 Runs a few hundred steps on CPU by default:
 
@@ -16,18 +18,14 @@ import argparse
 import tempfile
 import time
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.p3sapp_summarizer import CONFIG, SMOKE
-from repro.core.dataset import Dataset
-from repro.core.expr import abstract_expr, col, title_expr
 from repro.data.batching import seq2seq_specs
 from repro.data.synthetic import write_corpus
-from repro.models.seq2seq import Seq2Seq
-from repro.optim.adamw import AdamW, warmup_cosine
-from repro.runtime.fault_tolerance import TrainController
+from repro.launch.env import enable_compile_cache
+from repro.runtime.summarizer import case_study_chain, train_summarizer
 
 
 def main() -> None:
@@ -35,80 +33,43 @@ def main() -> None:
     ap.add_argument("--steps", type=int, default=300)
     ap.add_argument("--batch-size", type=int, default=32)
     ap.add_argument("--corpus-mb", type=float, default=4.0)
+    ap.add_argument("--workers", type=int, default=2)
     ap.add_argument("--smoke", action="store_true", help="tiny model config")
     ap.add_argument("--ckpt-dir", default=None)
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = SMOKE if args.smoke else CONFIG
+    t0 = time.perf_counter()
     corpus = tempfile.mkdtemp(prefix="p3sapp_corpus_")
     write_corpus(corpus, total_bytes=int(args.corpus_mb * 1e6), n_files=8, seed=1)
+    held_out = tempfile.mkdtemp(prefix="p3sapp_heldout_")
+    write_corpus(held_out, total_bytes=200_000, n_files=1, seed=2)
 
-    t0 = time.perf_counter()
-    # The full preprocessing flow is one lazy plan of column expressions;
-    # nothing executes yet.
-    keep = col("title").not_empty() & col("abstract").not_empty()
-    clean = (
-        Dataset.from_json_dirs([corpus])
-        .where(keep)
-        .drop_duplicates()
-        .transform(abstract=abstract_expr(), title=title_expr())
-        .where(keep)
+    run = train_summarizer(
+        [corpus],
+        cfg,
+        steps=args.steps,
+        ckpt_dir=args.ckpt_dir or tempfile.mkdtemp(prefix="p3sapp_ckpt_"),
+        batch_size=args.batch_size,
+        workers=args.workers,
     )
-    records, timings = clean.execute(optimize=True)
-    print(f"P3SAPP preprocessing: {timings.cumulative:.2f}s, {len(records)} records")
+    if run.first_step:
+        print(f"resumed from step {run.first_step}")
+    print(f"shards streamed by the {run.feed_stats.get('executor')} executor; "
+          f"train-step traces per bucket cell: {sorted(run.traces.values())}")
+    if run.history:
+        print(f"step {run.history[0]['step']}: loss={run.history[0]['loss']:.3f}")
+        print(f"step {run.history[-1]['step']}: loss={run.history[-1]['loss']:.3f}")
 
-    # Vocabulary fitting is a plan verb: per-shard word counts merged on
-    # the driver when streaming, the memoized frame here (one clean pass).
-    tok = clean.fit_vocab(vocab_size=cfg.vocab_size)
-    train_ds, val_ds = clean.split(val_fraction=0.1, seed=0)
+    # validation loss + greedy samples (paper Algorithm 3) on a held-out
+    # corpus, encoded with the fitted vocabulary
     specs = seq2seq_specs(cfg.max_abstract_len, cfg.max_title_len)
-    # ingest → where → transform → tokenize → batched → prefetch →
-    # device_batches: the cleaned frame is memoized, so this reuses the
-    # pass above; paired 2-D length-bucketed assembly trims encoder *and*
-    # decoder padding to a small fixed grid (one jit compile per cell).
-    loader = (
-        train_ds.tokenize(tok, specs)
-        .batched(
-            args.batch_size, shuffle=True,
-            bucket_by=("encoder_tokens", "decoder_tokens"),
-        )
-        .prefetch(2)
-        .device_batches(epochs=None)
-    )
-    val = val_ds.tokenize(tok, specs).arrays()
-    n_train = len(records) - len(next(iter(val.values())))
-    print(f"train={n_train} val={len(next(iter(val.values())))}")
-
-    model = Seq2Seq(cfg)
-    opt = AdamW(learning_rate=warmup_cosine(3e-3, 20, args.steps), weight_decay=1e-4)
-
-    @jax.jit
-    def train_step(params, opt_state, batch):
-        loss, grads = jax.value_and_grad(model.loss)(params, batch)
-        params, opt_state, gnorm = opt.update(grads, opt_state, params)
-        return params, opt_state, {"loss": loss, "grad_norm": gnorm}
-
-    def init_state():
-        params = model.init(jax.random.PRNGKey(0))
-        return params, opt.init(params)
-
-    ckpt_dir = args.ckpt_dir or tempfile.mkdtemp(prefix="p3sapp_ckpt_")
-    controller = TrainController(ckpt_dir, train_step, init_state, save_every=100)
-    if controller.resumed:
-        print(f"resumed from step {controller.step}")
-
-    try:
-        history = controller.run(iter(loader), n_steps=args.steps)
-    finally:
-        loader.close()  # endless epoch stream; stop the prefetch thread cleanly
-    if history:
-        print(f"step {history[0]['step']}: loss={history[0]['loss']:.3f}")
-        print(f"step {history[-1]['step']}: loss={history[-1]['loss']:.3f}")
-
-    # validation loss + greedy samples (paper Algorithm 3)
-    val_loss = float(model.loss(controller.params, {k: jnp.asarray(v[:64]) for k, v in val.items()}))
+    val = case_study_chain([held_out]).tokenize(run.tokenizer, specs).arrays()
+    model, params, tok = run.model, run.params, run.tokenizer
+    val_loss = float(model.loss(params, {k: jnp.asarray(v[:64]) for k, v in val.items()}))
     print(f"val loss: {val_loss:.3f}")
-    gen = model.generate(controller.params, val["encoder_tokens"][:3])
+    gen = model.generate(params, val["encoder_tokens"][:3])
     for i in range(3):
         print(f"  gold: {tok.decode(val['decoder_tokens'][i])}")
         print(f"  pred: {tok.decode(np.asarray(gen[i]))}\n")

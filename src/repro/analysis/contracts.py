@@ -11,7 +11,8 @@ enforced only by docstrings:
 * ``R002`` — fork-side byte-kernel paths stay module-level-jax-free:
   ``<pkg>.core.bytesops`` / ``core.executor`` / ``core.pipeline`` run
   inside forked process-pool workers, and jax is fork-unsafe (the
-  pallas backend imports it lazily, post-fork-check, on purpose).
+  pallas backend imports it lazily, and the parent hands workers its
+  jax-free form, ``bytesops.worker_backend``).
 * ``R003`` — cache and heartbeat file writes are atomic: any function
   in the cache/heartbeat modules that writes a file must stage through
   a temp file and ``os.replace`` (a monitor must never read a torn

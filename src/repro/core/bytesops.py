@@ -52,7 +52,8 @@ the chain is segmented into
 op, the paper-faithful P3SAPP executor), ``fused`` (megapass), and
 ``pallas`` (megapass whose scan passes offload to the
 ``kernels/text_clean`` Pallas kernel when the pass matches the kernel's
-shape, falling back to the host scan otherwise).  Selection:  explicit
+shape, falling back to the host scan otherwise; worker processes run its
+host form, :func:`worker_backend`).  Selection:  explicit
 argument > ``REPRO_BYTES_BACKEND`` env var > ``loops``.  **All backends
 are byte-identical by contract**; any chain the megapass compiler cannot
 prove exact (e.g. a LUT that remaps the row separator) falls back to
@@ -671,6 +672,13 @@ def resolve_backend(backend: str | None = None) -> str:
     return b
 
 
+def worker_backend(backend: str) -> str:
+    """The backend an out-of-process worker runs for ``backend``: the
+    device belongs to the parent, so ``pallas`` becomes its host form,
+    ``fused`` (the same megapass with the host scan, byte-identical)."""
+    return "fused" if backend == "pallas" else backend
+
+
 @dataclass(frozen=True)
 class ScanPass:
     """A maximal LUT/SPAN run lowered to one sweep + one compaction.
@@ -889,31 +897,30 @@ def _pallas_scan_args(sp: ScanPass) -> dict | None:
     }
 
 
-def _run_scan_pallas(buf: np.ndarray, sp: ScanPass) -> np.ndarray:
-    """Offload a scan pass to the Pallas text-clean kernel when it matches
-    the kernel's shape; byte-identical host fallback otherwise (also taken
-    when jax is absent, e.g. on the jax-free remote shard workers).
+def _run_scan_pallas(
+    buf: np.ndarray, sp: ScanPass, stats: dict | None = None
+) -> np.ndarray:
+    """Offload a scan pass to the Pallas text-scan kernel when it matches
+    the kernel's shape; the byte-identical host scan otherwise.
 
-    Multiprocessing children (the fork-based process shard executor and
-    the pipeline's process pool) always take the host fallback: jax is
-    multithreaded, so touching it in a forked child of a process whose
-    parent may already have imported it is a deadlock — and the fallback
-    is byte-identical by contract, so declining costs only the offload."""
+    Only the process that owns the device runs this: out-of-process
+    workers (forked pools, remote TCP workers) are handed
+    :func:`worker_backend` by their parent and never reach jax. ``stats``
+    counts ``pallas_calls`` (the kernel produced the bytes) and
+    ``pallas_declines`` (the bridge refused and the host scan ran)."""
     kwargs = _pallas_scan_args(sp)
     if kwargs is None or not sp.spans or buf.size == 0:
         return _run_scan(buf, sp)  # pure-LUT passes don't pay padding traffic
-    import multiprocessing as _mp
-
-    if _mp.parent_process() is not None:
-        return _run_scan(buf, sp)
     try:
         from repro.kernels.text_clean.ops import scan_flat
-    except Exception:
-        return _run_scan(buf, sp)
-    out = scan_flat(buf, **kwargs)
-    if out is None:  # kernel declined (no jax, padding blow-up, …)
-        return _run_scan(buf, sp)
-    return out
+    except ImportError:  # jax is not installed
+        out = None
+    else:
+        out = scan_flat(buf, **kwargs)
+    if stats is not None:
+        key = "pallas_declines" if out is None else "pallas_calls"
+        stats[key] = stats.get(key, 0) + 1
+    return _run_scan(buf, sp) if out is None else out
 
 
 def _span_indices(starts: np.ndarray, lens: np.ndarray) -> np.ndarray:
@@ -980,11 +987,15 @@ def _run_word(buf: np.ndarray, wp: WordPass) -> np.ndarray:
 
 
 def run_megapass(
-    buf: np.ndarray, passes: Sequence[tuple[str, object]], *, pallas: bool = False
+    buf: np.ndarray,
+    passes: Sequence[tuple[str, object]],
+    *,
+    pallas: bool = False,
+    stats: dict | None = None,
 ) -> np.ndarray:
     for kind, p in passes:
         if kind == "scan":
-            buf = _run_scan_pallas(buf, p) if pallas else _run_scan(buf, p)
+            buf = _run_scan_pallas(buf, p, stats) if pallas else _run_scan(buf, p)
         elif kind == "word":
             buf = _run_word(buf, p)
         else:
@@ -1012,16 +1023,21 @@ def _compile_cached(ops: Sequence[Op]):
 
 
 def execute_ops(
-    buf: np.ndarray, ops: Sequence[Op], backend: str | None = None
+    buf: np.ndarray,
+    ops: Sequence[Op],
+    backend: str | None = None,
+    *,
+    stats: dict | None = None,
 ) -> np.ndarray:
     """Run an op chain under the selected backend (see module docstring).
 
     Byte-identical across backends; chains the megapass compiler cannot
-    prove exact fall back to the loops backend wholesale."""
+    prove exact fall back to the loops backend wholesale. ``stats``, when
+    given, counts the ``pallas`` backend's kernel calls and declines."""
     b = resolve_backend(backend)
     if b == "loops" or not ops:
         return apply_ops(buf, ops)
     prog = _compile_cached(ops)
     if prog is None:
         return apply_ops(buf, ops)
-    return run_megapass(buf, prog, pallas=(b == "pallas"))
+    return run_megapass(buf, prog, pallas=(b == "pallas"), stats=stats)

@@ -234,12 +234,12 @@ class Dataset:
         return self._derive(P.Tokenize(tokenizer, specs), [s.name for s in specs])
 
     # -- vocabulary fitting (terminal; Spark CountVectorizer-style) --------
-    def _counts_mode(self) -> str:
+    def _counts_mode(self, executor: str | None) -> str:
         """How ``fit_vocab`` counts: ``"stream"`` (one pass through the
         shard executors), ``"two-pass"`` (canonical-survivor dedup
-        election, then a counting pass over the survivors — the streaming
-        protocol for partial-subset ``drop_duplicates``), or ``"whole"``
-        (count the materialized frame)."""
+        election, then a counting pass over the survivors — see
+        :func:`repro.core.plan.dedup_mode`), or ``"whole"`` (count the
+        materialized frame)."""
         owner = self._frame_prefix_dataset()
         if self._has_memoized_frame():
             return "whole"  # already materialized: count that frame
@@ -247,17 +247,12 @@ class Dataset:
             return "whole"
         if any(isinstance(n, P.Split) for n in owner._nodes):
             return "whole"  # whole-frame only
-        src_fields = set(owner._nodes[0].fields)
-        dedups = [n for n in owner._nodes if isinstance(n, P.DropDuplicates)]
-        partial = [d for d in dedups if not set(d.subset) >= src_fields]
-        if not partial:
-            return "stream"  # full-subset dedup: duplicate rows interchange
-        if len(dedups) == 1:
-            return "two-pass"
-        # A partial-subset dedup stacked with another dedup: the election
-        # pass would itself run under scheduling-dependent cross-shard
-        # state, so fall back to the exact whole-frame count.
-        return "whole"
+        mode = P.dedup_mode(owner._nodes, executor)
+        if mode == "stacked":
+            # The election pass would itself run under scheduling-dependent
+            # cross-shard state: fall back to the exact whole-frame count.
+            return "whole"
+        return "two-pass" if mode == "two-pass" else "stream"
 
     def fit_vocab(
         self,
@@ -303,7 +298,8 @@ class Dataset:
             raise KeyError(f"unknown columns {unknown}; schema is {list(owner.schema)}")
         counts: Counter = Counter()
         n_workers = self._resolve_workers(workers, default=2)
-        mode = self._counts_mode()
+        executor = executor or self._options.get("executor")
+        mode = self._counts_mode(executor)
         if mode != "whole":
             frame_nodes, _ = P.split_plan(owner._nodes)
             if optimize:
@@ -311,7 +307,7 @@ class Dataset:
             exec_kw = dict(
                 workers=n_workers,
                 cache_dir=self._resolve_cache_dir(),
-                executor=executor or self._options.get("executor"),
+                executor=executor,
                 remote=self._options.get("remote"),
             )
             shards = ing.list_shards(frame_nodes[0].directories)
@@ -853,6 +849,7 @@ class Dataset:
         executor: str | None = None,
         overlap: bool = False,
         profiler: Any = None,
+        stats: dict | None = None,
     ):
         """Terminal: batches prefetched onto device via AsyncLoader, so host
         preprocessing overlaps device compute end-to-end. With
@@ -860,13 +857,15 @@ class Dataset:
         :class:`~repro.core.device_pipeline.DeviceFeed` instead: batches
         snap onto the plan's fixed bucket grid, transfers double-buffer
         ahead of compute, and the feed's :class:`OverlapProfiler` accounts
-        device-idle time per step."""
+        device-idle time per step. ``stats`` is passed to
+        :meth:`iter_batches`."""
         self._require_valid(optimize=optimize)
         node = next((n for n in self._nodes if isinstance(n, P.Prefetch)), None)
         depth = prefetch if prefetch is not None else (node.prefetch if node else 2)
         shard = sharding if sharding is not None else (node.sharding if node else None)
         it = self.iter_batches(
-            workers=workers, optimize=optimize, epochs=epochs, executor=executor
+            workers=workers, optimize=optimize, epochs=epochs, executor=executor,
+            stats=stats,
         )
         if overlap or profiler is not None:
             from .device_pipeline import DeviceFeed

@@ -363,9 +363,13 @@ class DeviceCleaner:
     lose their apostrophes instead of expanding; see DESIGN.md). The host
     half is a ``col()`` expression chain (word-level verbs only), compiled
     once and applied to the flat byte buffers the device pass returns.
+    ``interpret=None`` runs the kernel compiled on a TPU and interpreted
+    elsewhere (the capability check of ``clean_rows``).
     """
 
-    def __init__(self, word_expr: Callable | None = None, interpret: bool = True):
+    def __init__(
+        self, word_expr: Callable | None = None, interpret: bool | None = None
+    ):
         from . import expr as E
 
         self.interpret = interpret
@@ -396,7 +400,7 @@ class DeviceCleaner:
         return out
 
 
-def device_case_study_cleaner(interpret: bool = True) -> DeviceCleaner:
+def device_case_study_cleaner(interpret: bool | None = None) -> DeviceCleaner:
     """The case-study word tail (stopwords + short words) over the device
     char-level pass — expression form of the old Stage pair."""
     return DeviceCleaner(

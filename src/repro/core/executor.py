@@ -55,7 +55,7 @@ import time
 import traceback
 import dataclasses
 from collections import Counter
-from dataclasses import dataclass
+from dataclasses import dataclass, replace
 from pathlib import Path
 from typing import Any, Iterator, Sequence
 
@@ -174,8 +174,11 @@ class ShardProgram:
     run under (resolved at compile time from the explicit option or
     ``REPRO_BYTES_BACKEND``, so it travels — pickled with the program —
     to process-pool and remote workers whose environment may differ).
-    Backends are byte-identical by contract, which is why the *cache*
-    lineage fingerprints deliberately exclude it."""
+    ``worker_backend`` is what those out-of-process workers run instead,
+    decided here, in the parent: the device belongs to the parent, so a
+    worker never imports jax (:meth:`for_workers`). Backends are
+    byte-identical by contract, which is why the *cache* lineage
+    fingerprints deliberately exclude them."""
 
     fields: tuple[str, ...]
     steps: tuple[Step, ...]
@@ -183,10 +186,15 @@ class ShardProgram:
     tokens: TokenPlan | None = None
     count_words: tuple[str, ...] = ()
     backend: str = "loops"
+    worker_backend: str = "loops"
 
     @property
     def has_dedup(self) -> bool:
         return any(kind == "dedup" for kind, _ in self.steps)
+
+    def for_workers(self) -> "ShardProgram":
+        """This program as an out-of-process worker runs it."""
+        return replace(self, backend=self.worker_backend)
 
 
 class UnsupportedPlanError(ValueError):
@@ -229,13 +237,15 @@ def compile_shard_program(
             steps.append(("filter", comp))
         else:
             raise UnsupportedPlanError(f"not shard-executable: {node.describe()}")
+    backend = EngineConfig().resolve_backend(backend)
     return ShardProgram(
         tuple(src.fields),
         tuple(steps),
         tuple(output_columns),
         tokens=tokens,
         count_words=tuple(count_words),
-        backend=EngineConfig().resolve_backend(backend),
+        backend=backend,
+        worker_backend=B.worker_backend(backend),
     )
 
 
@@ -1618,10 +1628,14 @@ class ProcessShardExecutor:
         ctx = mp.get_context("fork" if "fork" in methods else "spawn")
         self._task_q = ctx.Queue()
         self._result_q = ctx.Queue()
+        worker_program = program.for_workers()  # the device stays here
         self._procs = [
             ctx.Process(
                 target=_worker_main,
-                args=(self._task_q, self._result_q, program, cache_dir, self.run_id),
+                args=(
+                    self._task_q, self._result_q, worker_program, cache_dir,
+                    self.run_id,
+                ),
                 daemon=True,
             )
             for _ in range(max(int(workers), 1))

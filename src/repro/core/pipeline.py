@@ -71,7 +71,9 @@ def _split_on_rows(buf: np.ndarray, k: int) -> list[np.ndarray]:
 def _run_ops(args) -> np.ndarray:
     """Pool task: ``(ops, buf)`` or ``(ops, buf, backend)``. The driver
     resolves the backend through :class:`EngineConfig` before fan-out, so
-    every chunk of a run uses the same backend regardless of worker env."""
+    every chunk of a run uses the same backend regardless of worker env —
+    its :func:`~repro.core.bytesops.worker_backend` form, since the device
+    belongs to the parent."""
     ops, buf = args[0], args[1]
     backend = args[2] if len(args) > 2 else None
     return B.execute_ops(buf, ops, backend)
@@ -129,7 +131,9 @@ def run_column_plans(
                 res = _run_ops((ops, src, backend))
             else:
                 chunks = _split_on_rows(src, workers)
-                parts = list(pool.map(_run_ops, [(ops, c, backend) for c in chunks]))
+                parts = list(pool.map(
+                    _run_ops, [(ops, c, B.worker_backend(backend)) for c in chunks]
+                ))
                 res = np.concatenate(parts) if parts else src
             bufs[out_col] = res
             out = out.ensure_column(out_col).with_flat(out_col, res)

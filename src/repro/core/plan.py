@@ -253,6 +253,31 @@ def split_plan(nodes: Sequence[PlanNode]) -> tuple[list[PlanNode], list[PlanNode
     return frame_nodes, array_nodes
 
 
+def dedup_mode(frame_nodes: Sequence[PlanNode], executor: str | None) -> str:
+    """How a streamed plan deduplicates across shards.
+
+    ``"one-pass"``: no dedup, or full-subset dedups, whose duplicate rows
+    are interchangeable, kept in the thread executor's cross-shard state.
+    ``"two-pass"``: the canonical-survivor protocol (see
+    :func:`repro.core.executor.split_dedup_programs`) for a single dedup
+    whose subset is partial (which variant survives matters), or whose
+    run explicitly asked for processes or remote workers: only the
+    two-pass programs are per-shard, so a full-subset dedup would
+    otherwise fall back to threads. ``"stacked"``: a partial dedup stacked
+    with another dedup, which cannot stream."""
+    from .engine_config import EngineConfig
+
+    src_fields = set(frame_nodes[0].fields)
+    dedups = [n for n in frame_nodes if isinstance(n, DropDuplicates)]
+    partial = [d for d in dedups if not set(d.subset) >= src_fields]
+    if partial:
+        return "two-pass" if len(dedups) == 1 else "stacked"
+    out_of_process = EngineConfig(executor=executor).resolve_executor() in (
+        "process", "remote",
+    )
+    return "two-pass" if len(dedups) == 1 and out_of_process else "one-pass"
+
+
 # ---------------------------------------------------------------------------
 # Optimizer
 # ---------------------------------------------------------------------------
@@ -622,6 +647,7 @@ def run_project_frame(
     the compiled expression, unflatten once. Pure op chains optionally fan
     out over a process pool by splitting the buffer on row boundaries
     (every byte op is row-local, so this is embarrassingly parallel)."""
+    from . import bytesops as B
     from .pipeline import _run_ops, _split_on_rows
 
     flat: dict[str, np.ndarray] = {}
@@ -647,8 +673,9 @@ def run_project_frame(
             elif pool is not None and comp[0] == "chain":
                 src = lookup(comp[1])
                 chunks = _split_on_rows(src, workers)
+                task_backend = B.worker_backend(B.resolve_backend(backend))
                 parts = list(
-                    pool.map(_run_ops, [(list(comp[2]), c, backend) for c in chunks])
+                    pool.map(_run_ops, [(list(comp[2]), c, task_backend) for c in chunks])
                 )
                 buf = np.concatenate(parts) if parts else src
             else:
@@ -901,9 +928,11 @@ def stream_batches(
     deterministic run-to-run and across executors; records additionally
     match whole-frame execution as a multiset.
     Full-subset dedup keeps that guarantee directly — duplicate rows are
-    interchangeable. A *partial*-subset drop_duplicates (where the variant
-    that survives matters) streams via the two-pass canonical-survivor
-    protocol instead: an election pass picks each key's whole-frame
+    interchangeable — in the thread executor's cross-shard state. A
+    *partial*-subset drop_duplicates (where the variant that survives
+    matters), or any single dedup in a run that explicitly asks for
+    processes or remote workers (:func:`dedup_mode`), streams via the
+    two-pass canonical-survivor protocol instead: an election pass picks each key's whole-frame
     keep-first row, then every epoch runs the pure per-shard ``dedup_take``
     program (see :func:`repro.core.executor.split_dedup_programs`). Only a
     partial dedup *stacked with another dedup* is rejected.
@@ -948,15 +977,14 @@ def stream_batches(
     if tok is None or batch is None:
         raise ValueError("streaming needs .tokenize(...) and .batch(...) in the plan")
 
-    dedups = [n for n in frame_nodes[1:] if isinstance(n, DropDuplicates)]
-    partial = [d for d in dedups if not set(d.subset) >= set(src.fields)]
-    if partial and len(dedups) > 1:
+    mode = dedup_mode(frame_nodes, executor)
+    if mode == "stacked":
         # The election pass for one partial dedup would itself run under
         # the scheduling-dependent cross-shard state of the other.
         raise ValueError(
-            f"streaming drop_duplicates({list(partial[0].subset)}) with "
-            f"partial subsets cannot stack with another drop_duplicates; "
-            f"drop .prefetch() for whole-frame execution"
+            "streaming drop_duplicates with partial subsets cannot stack "
+            "with another drop_duplicates; drop .prefetch() for whole-frame "
+            "execution"
         )
 
     shards = ing.list_shards(src.directories)
@@ -970,7 +998,7 @@ def stream_batches(
         vocab_fp=tok.tokenizer.fingerprint,
     )
     row_filters = None
-    if partial:
+    if mode == "two-pass":
         # Two-pass canonical-survivor protocol (shared with fit_vocab):
         # elect the whole-frame keep-first survivor rows once, then every
         # epoch streams the pure per-shard dedup_take program — identical

@@ -445,7 +445,7 @@ class RemoteShardExecutor:
         )
         self._coord = Coordinator(
             self._shards,
-            program,
+            program.for_workers(),  # the device stays with this process
             cache_dir=cache_dir,
             row_filters=row_filters,
             lease_s=float(opts.get("lease_s", 30.0)),

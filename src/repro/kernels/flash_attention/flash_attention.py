@@ -19,8 +19,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..pallas_compat import tpu_compiler_params
-
 NEG_INF = -1e30
 
 
@@ -123,7 +121,7 @@ def flash_attention(
             pltpu.VMEM((blk_q,), jnp.float32),
             pltpu.VMEM((blk_q, d), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

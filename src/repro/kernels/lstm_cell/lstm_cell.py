@@ -16,8 +16,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from ..pallas_compat import tpu_compiler_params
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _lstm_kernel(x_ref, h_ref, c_ref, wx_ref, wh_ref, b_ref, ho_ref, co_ref):
@@ -84,7 +83,7 @@ def lstm_cell(
             pl.BlockSpec((blk_b, blk_h), lambda bi, hi: (bi, hi)),
         ],
         out_shape=out_shape,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
         ),
         interpret=interpret,

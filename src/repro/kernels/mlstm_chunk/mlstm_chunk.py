@@ -24,7 +24,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..pallas_compat import tpu_compiler_params
+from ..pallas_compat import lane_prefix_sum
 
 NEG_INF = -1e30
 
@@ -46,15 +46,15 @@ def _mlstm_chunk_kernel(
     qb = q_ref[0].astype(jnp.float32)  # (L, dh)
     kb = k_ref[0].astype(jnp.float32)
     vb = v_ref[0].astype(jnp.float32)
-    ib = i_ref[...].astype(jnp.float32)  # (1, L) gate pre-activations
-    fb = f_ref[...].astype(jnp.float32)
+    ib = i_ref[0, 0].astype(jnp.float32)  # (1, L) gate pre-activations
+    fb = f_ref[0, 0].astype(jnp.float32)
 
     C_in = c_scr[...]  # (dh_v, dh_k)
     n_in = n_scr[...]  # (1, dh_k)
-    m_in = m_scr[0, 0]
+    m_in = m_scr[...]  # (1, 1)
 
     lf = jax.nn.log_sigmoid(fb)  # (1, L)
-    b_cum = jnp.cumsum(lf, axis=1)
+    b_cum = lane_prefix_sum(lf)
     x = ib - b_cum  # (1, L)
     # running max over j<=t via masked (L, L) max (L is small: O(L^2) VPU)
     tt = jax.lax.broadcasted_iota(jnp.int32, (L, L), 0)
@@ -80,8 +80,8 @@ def _mlstm_chunk_kernel(
     o_ref[0] = h.astype(o_ref.dtype)
 
     # state update at t = L-1
-    b_last = b_cum[0, L - 1]
-    m_out = jnp.maximum(b_last + m_in, jnp.max(x) + b_last)
+    b_last = b_cum[:, L - 1 :]  # (1, 1)
+    m_out = jnp.maximum(b_last + m_in, jnp.max(x, axis=1, keepdims=True) + b_last)
     s_out = jnp.exp(b_last + m_in - m_out)
     w_j = jnp.exp((b_last - b_cum) + ib - m_out)  # (1, L)
     kw = kb * w_j[0][:, None]  # (L, dh)
@@ -89,7 +89,7 @@ def _mlstm_chunk_kernel(
         vb, kw, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )  # (dh_v, dh_k)
     n_scr[...] = s_out * n_in + jnp.sum(kw, axis=0)[None, :]
-    m_scr[0, 0] = m_out
+    m_scr[...] = m_out
 
 
 def mlstm_chunk(
@@ -107,7 +107,9 @@ def mlstm_chunk(
     n_chunks = s // chunk
 
     qkv_spec = pl.BlockSpec((1, chunk, dh), lambda b, c: (b, c, 0))
-    gate_spec = pl.BlockSpec((1, chunk), lambda b, c: (b, c))
+    # Gates ride as (bh, n_chunks, 1, chunk): a chunk's block then ends in
+    # (1, chunk), equal to the array's last two dims (TPU (8, 128) rule).
+    gate_spec = pl.BlockSpec((1, 1, 1, chunk), lambda b, c: (b, c, 0, 0))
     kernel = functools.partial(_mlstm_chunk_kernel, chunk=chunk)
     return pl.pallas_call(
         kernel,
@@ -120,8 +122,9 @@ def mlstm_chunk(
             pltpu.VMEM((1, dh), jnp.float32),
             pltpu.VMEM((1, 1), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(q, k, v, i_gate, f_gate)
+    )(q, k, v, i_gate.reshape(bh, n_chunks, 1, chunk),
+      f_gate.reshape(bh, n_chunks, 1, chunk))

@@ -1,41 +1,29 @@
-"""Version compatibility shims for Pallas TPU kernels.
-
-The TPU compiler-params dataclass was renamed across JAX releases
-(``pltpu.TPUCompilerParams`` → ``pltpu.CompilerParams``); resolving it here
-keeps every kernel importable (and runnable under ``interpret=True`` on CPU)
-on any JAX the container ships.
-"""
+"""Helpers shared by the Pallas kernels: the device capability check and
+a prefix sum that lowers in Mosaic (which has no ``cumsum``)."""
 
 from __future__ import annotations
 
+import jax
+import jax.numpy as jnp
 from jax.experimental.pallas import tpu as pltpu
-
-_PARAMS_CLS = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams", None
-)
-
-
-def tpu_compiler_params(**kwargs):
-    """``compiler_params=`` value for ``pl.pallas_call`` on any JAX version.
-
-    Returns None (meaning "compiler defaults") when neither class exists or
-    the installed class rejects the requested fields — correctness never
-    depends on these hints, only scheduling.
-    """
-    if _PARAMS_CLS is None:  # pragma: no cover - ancient jax
-        return None
-    try:
-        return _PARAMS_CLS(**kwargs)
-    except TypeError:  # pragma: no cover - field renamed/removed upstream
-        return None
 
 
 def has_tpu() -> bool:
-    """True when a TPU backend is attached — the capability check deciding
-    whether kernels run compiled (``interpret=False``) or must interpret."""
-    import jax
+    """True when JAX's default backend is a TPU — the capability check
+    deciding whether kernels run compiled (``interpret=False``) or must
+    interpret. A backend that fails to initialise raises here instead of
+    reading as "no TPU"."""
+    return jax.default_backend() == "tpu"
 
-    try:
-        return any(d.platform == "tpu" for d in jax.devices())
-    except Exception:  # pragma: no cover - backend init failure == no TPU
-        return False
+
+def lane_prefix_sum(v: jax.Array) -> jax.Array:
+    """Inclusive prefix sum along the last axis of a 2-D in-kernel value:
+    log2(width) Hillis-Steele steps, each a lane rotation with the wrapped
+    lanes masked to zero."""
+    width = v.shape[-1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, v.shape, v.ndim - 1)
+    shift = 1
+    while shift < width:
+        v = v + jnp.where(lane >= shift, pltpu.roll(v, shift, v.ndim - 1), 0)
+        shift *= 2
+    return v

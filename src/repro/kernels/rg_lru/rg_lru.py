@@ -22,22 +22,18 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..pallas_compat import tpu_compiler_params
-
 
 def _rg_lru_kernel(a_ref, b_ref, h0_ref, o_ref, h_scr, *, blk_s: int):
     si = pl.program_id(2)
 
     @pl.when(si == 0)
     def _init():
-        h_scr[...] = h0_ref[0]
-
-    a = a_ref[0]  # (blk_s, blk_d)
-    b = b_ref[0]
+        h_scr[...] = h0_ref[0]  # (1, blk_d)
 
     def step(t, h):
-        h = a[t] * h + b[t]
-        o_ref[0, t, :] = h
+        row = pl.ds(t, 1)
+        h = a_ref[0, row, :] * h + b_ref[0, row, :]
+        o_ref[0, row, :] = h
         return h
 
     h_scr[...] = jax.lax.fori_loop(0, blk_s, step, h_scr[...])
@@ -55,6 +51,9 @@ def rg_lru(
     bt, s, d = a.shape
     if h0 is None:
         h0 = jnp.zeros((bt, d), jnp.float32)
+    # A unit axis makes the state block's last two dims (1, blk_d) match
+    # the array's (1, d) — the TPU (8, 128) block rule.
+    h0 = h0.reshape(bt, 1, d)
     blk_s = min(blk_s, s)
     blk_d = min(blk_d, d)
     grid = (bt, pl.cdiv(d, blk_d), pl.cdiv(s, blk_s))
@@ -66,12 +65,12 @@ def rg_lru(
         in_specs=[
             pl.BlockSpec((1, blk_s, blk_d), lambda bi, di, si: (bi, si, di)),
             pl.BlockSpec((1, blk_s, blk_d), lambda bi, di, si: (bi, si, di)),
-            pl.BlockSpec((1, blk_d), lambda bi, di, si: (bi, di)),
+            pl.BlockSpec((1, 1, blk_d), lambda bi, di, si: (bi, 0, di)),
         ],
         out_specs=pl.BlockSpec((1, blk_s, blk_d), lambda bi, di, si: (bi, si, di)),
         out_shape=jax.ShapeDtypeStruct((bt, s, d), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((blk_d,), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        scratch_shapes=[pltpu.VMEM((1, blk_d), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

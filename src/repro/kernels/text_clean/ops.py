@@ -23,11 +23,11 @@ import jax
 import numpy as np
 
 from ..pallas_compat import has_tpu
-from .text_clean import text_clean, text_scan
+from .text_clean import LANE_TILE, ROW_TILE, block_rows, text_clean, text_scan
 
 
 @partial(jax.jit, static_argnames=("strip_html", "blk_rows", "interpret"))
-def text_clean_op(rows, *, strip_html: bool = True, blk_rows: int = 256,
+def text_clean_op(rows, *, strip_html: bool = True, blk_rows: int | None = None,
                   interpret: bool = False):
     return text_clean(rows, strip_html=strip_html, blk_rows=blk_rows, interpret=interpret)
 
@@ -37,7 +37,7 @@ def text_clean_op(rows, *, strip_html: bool = True, blk_rows: int = 256,
     static_argnames=("lower", "strip_html", "strip_parens", "blk_rows", "interpret"),
 )
 def text_scan_op(rows, *, lower: bool = True, strip_html: bool = False,
-                 strip_parens: bool = False, blk_rows: int = 256,
+                 strip_parens: bool = False, blk_rows: int | None = None,
                  interpret: bool = False):
     return text_scan(rows, lower=lower, strip_html=strip_html,
                      strip_parens=strip_parens, blk_rows=blk_rows,
@@ -72,7 +72,8 @@ def clean_rows(
         return []
     if interpret is None:
         interpret = not has_tpu()
-    mat = pack_rows(rows)
+    width = max((len(r.encode("utf-8", errors="ignore")) for r in rows), default=1)
+    mat = pack_rows(rows, width=_round_up(max(width, 1), LANE_TILE))
     cleaned = text_clean_op(mat, strip_html=strip_html, interpret=interpret)
     return unpack_rows(np.asarray(cleaned))
 
@@ -85,6 +86,29 @@ _MAX_BLOWUP = 8.0
 # Same knob as repro.core.engine_config.ENV_PALLAS_INTERPRET; read directly
 # here to keep this bridge importable without the core engine layer.
 INTERPRET_ENV = "REPRO_PALLAS_INTERPRET"
+
+
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
+
+
+def _ladder(n: int) -> int:
+    """Smallest of 1, 2, 3, 4, 6, 8, 12, 16, ... (``2**k`` and
+    ``3 * 2**k``) that is >= ``n``: at most 1.5x padding, and a number of
+    distinct values logarithmic in ``n``."""
+    p = 1 << max(n - 1, 0).bit_length()
+    return p * 3 // 4 if p >= 4 and p * 3 // 4 >= n else p
+
+
+def padded_shape(n_rows: int, width: int) -> tuple[int, int]:
+    """The (rows, width) matrix ``scan_flat`` packs ``n_rows`` rows of at
+    most ``width`` bytes into. Widths round up to a power of two (at least
+    one lane tile) and rows up a 2**k / 3*2**k ladder of row tiles, then to
+    whole row blocks, so a corpus of shards compiles the kernel a handful
+    of times instead of once per shard."""
+    width_p = max(LANE_TILE, 1 << max(width - 1, 0).bit_length())
+    rows_p = _ladder(-(-n_rows // ROW_TILE)) * ROW_TILE
+    return _round_up(rows_p, min(block_rows(width_p), rows_p)), width_p
 
 
 def scan_flat(
@@ -119,18 +143,18 @@ def scan_flat(
     width = int(lens.max())
     if width == 0:
         return buf.copy()  # every row empty: nothing to scan
-    width_p = -(-width // 128) * 128  # TPU lane multiple; pad is space
-    if n * width_p > _MAX_PAD_BYTES or n * width_p > _MAX_BLOWUP * buf.size:
+    rows_p, width_p = padded_shape(n, width)  # pad bytes are spaces
+    if rows_p * width_p > _MAX_PAD_BYTES or n * width_p > _MAX_BLOWUP * buf.size:
         return None
     row_of = np.cumsum(sep, dtype=np.int64) - sep
     col = np.arange(buf.size, dtype=np.int64) - starts[row_of]
     payload = ~sep
     flat_pos = row_of[payload] * width_p + col[payload]
-    mat = np.full(n * width_p, 32, dtype=np.uint8)
+    mat = np.full(rows_p * width_p, 32, dtype=np.uint8)
     mat[flat_pos] = buf[payload]
     out_mat = np.asarray(
         text_scan_op(
-            mat.reshape(n, width_p),
+            mat.reshape(rows_p, width_p),
             lower=lower,
             strip_html=strip_html,
             strip_parens=strip_parens,

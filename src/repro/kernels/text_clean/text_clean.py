@@ -10,12 +10,13 @@ Input: a (rows, width) uint8 matrix of padded text rows. One VMEM pass:
 
 * lowercase via arithmetic range test (no gather — TPU-friendly),
 * tag-span removal via a per-row cumulative depth (rows are independent,
-  so ``jnp.cumsum`` along the width axis is exactly the span mask),
+  so a prefix sum along the width axis is exactly the span mask; Mosaic
+  has no cumsum, so it is a log-step sum of lane rotations),
 * unwanted-character classes mapped to space.
 
 Output: cleaned bytes with removed positions already set to space; the
 host only collapses whitespace (the only step needing compaction).
-Grid over row blocks; width stays whole per block (row-local cumsum).
+Grid over row blocks; width stays whole per block (row-local prefix sum).
 """
 
 from __future__ import annotations
@@ -25,10 +26,44 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from ..pallas_compat import tpu_compiler_params
+from ..pallas_compat import lane_prefix_sum
 
 SPACE = 32
+
+# Rows are uint8, whose native TPU tile is (32, 128): row blocks stay a
+# multiple of 32 and widths a multiple of 128.
+ROW_TILE = 32
+LANE_TILE = 128
+# Elements per block: about six int32 temporaries of one block live at
+# once in the prefix sum, so 2**18 elements keep a block near 6 MiB, inside
+# the default scoped VMEM with the double-buffered uint8 in/out blocks.
+_BLOCK_ELEMS = 1 << 18
+_MAX_BLK_ROWS = 512
+
+
+def block_rows(width: int) -> int:
+    """Row-block height for a ``width``-byte row: the largest multiple of
+    ``ROW_TILE`` (capped at 512) whose int32 temporaries fit one block's
+    VMEM budget. Wide rows (abstracts run to several KB) get short blocks."""
+    rows = (_BLOCK_ELEMS // max(width, 1)) // ROW_TILE * ROW_TILE
+    return min(max(rows, ROW_TILE), _MAX_BLK_ROWS)
+
+
+def _grid_call(kernel, rows: jax.Array, blk_rows: int | None, interpret: bool):
+    n, width = rows.shape
+    blk = min(blk_rows or block_rows(width), n)
+    spec = pl.BlockSpec((blk, width), lambda i: (i, 0))
+    return pl.pallas_call(
+        kernel,
+        grid=(pl.cdiv(n, blk),),
+        in_specs=[spec],
+        out_specs=spec,
+        out_shape=jax.ShapeDtypeStruct((n, width), jnp.uint8),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
+        interpret=interpret,
+    )(rows)
 
 
 def _clean_kernel(x_ref, o_ref, *, strip_html: bool):
@@ -42,7 +77,7 @@ def _clean_kernel(x_ref, o_ref, *, strip_html: bool):
     if strip_html:
         lt = (x == 60).astype(jnp.int32)  # '<'
         gt = (x == 62).astype(jnp.int32)  # '>'
-        depth = jnp.cumsum(lt - gt, axis=1)
+        depth = lane_prefix_sum(lt - gt)
         keep = (depth == 0) & (x != 62)
 
     # RemoveUnwantedCharacters: anything outside [a-z] -> space
@@ -55,23 +90,11 @@ def text_clean(
     rows: jax.Array,  # (n_rows, width) uint8, space padded
     *,
     strip_html: bool = True,
-    blk_rows: int = 256,
+    blk_rows: int | None = None,
     interpret: bool = False,
 ) -> jax.Array:
-    n, width = rows.shape
-    blk_rows = min(blk_rows, n)
     kernel = functools.partial(_clean_kernel, strip_html=strip_html)
-    return pl.pallas_call(
-        kernel,
-        grid=(pl.cdiv(n, blk_rows),),
-        in_specs=[pl.BlockSpec((blk_rows, width), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((blk_rows, width), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n, width), jnp.uint8),
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel",),
-        ),
-        interpret=interpret,
-    )(rows)
+    return _grid_call(kernel, rows, blk_rows, interpret)
 
 
 def _scan_kernel(x_ref, o_ref, *, lower: bool, strip_html: bool, strip_parens: bool):
@@ -94,12 +117,12 @@ def _scan_kernel(x_ref, o_ref, *, lower: bool, strip_html: bool, strip_parens: b
     if strip_html:
         lt = (x == 60).astype(jnp.int32)  # '<'
         gt = (x == 62).astype(jnp.int32)  # '>'
-        depth = jnp.cumsum(lt - gt, axis=1)
+        depth = lane_prefix_sum(lt - gt)
         alive = (depth <= 0) & (x != 62)
     if strip_parens:
         opens = (x == 40) & alive  # '('
         closes = (x == 41) & alive  # ')'
-        depth2 = jnp.cumsum(opens.astype(jnp.int32) - closes.astype(jnp.int32), axis=1)
+        depth2 = lane_prefix_sum(opens.astype(jnp.int32) - closes.astype(jnp.int32))
         alive &= (depth2 <= 0) & ~closes
     o_ref[...] = jnp.where(alive, x, 0).astype(jnp.uint8)
 
@@ -110,22 +133,10 @@ def text_scan(
     lower: bool = True,
     strip_html: bool = False,
     strip_parens: bool = False,
-    blk_rows: int = 256,
+    blk_rows: int | None = None,
     interpret: bool = False,
 ) -> jax.Array:
-    n, width = rows.shape
-    blk_rows = min(blk_rows, n)
     kernel = functools.partial(
         _scan_kernel, lower=lower, strip_html=strip_html, strip_parens=strip_parens
     )
-    return pl.pallas_call(
-        kernel,
-        grid=(pl.cdiv(n, blk_rows),),
-        in_specs=[pl.BlockSpec((blk_rows, width), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((blk_rows, width), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n, width), jnp.uint8),
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel",),
-        ),
-        interpret=interpret,
-    )(rows)
+    return _grid_call(kernel, rows, blk_rows, interpret)

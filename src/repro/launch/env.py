@@ -23,6 +23,7 @@ from __future__ import annotations
 import argparse
 import os
 import shlex
+from pathlib import Path
 from typing import Mapping, Sequence
 
 # Preload candidates, most specific first: full tcmalloc, then the
@@ -37,6 +38,29 @@ TCMALLOC_CANDIDATES: tuple[str, ...] = (
 # Keep numpy's large transient buffers (flat byte buffers, token arrays)
 # below tcmalloc's large-alloc report chatter.
 TCMALLOC_REPORT_THRESHOLD = "60000000000"
+
+
+# JAX's persistent compilation cache. An entry is only found again from the
+# same directory, so the fallback is one fixed path inside the checkout —
+# never a temporary name, a pid or a timestamp.
+COMPILE_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+DEFAULT_COMPILE_CACHE = Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
+    this sets no other directory; otherwise the cache goes to
+    ``<checkout>/.jax_cache``. Entry points call this from ``main()``,
+    never at import."""
+    configured = os.environ.get(COMPILE_CACHE_ENV)
+    if configured:
+        return configured
+    import jax
+
+    jax.config.update("jax_compilation_cache_dir", str(DEFAULT_COMPILE_CACHE))
+    return str(DEFAULT_COMPILE_CACHE)
 
 
 def find_tcmalloc(candidates: Sequence[str] | None = None) -> str | None:

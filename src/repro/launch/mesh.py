@@ -8,40 +8,14 @@ dry-run sees 512 placeholder host devices).
 from __future__ import annotations
 
 import jax
+from jax import shard_map
+from jax.sharding import AxisType, set_mesh
 
-try:  # jax >= 0.4.38; older versions predate explicit axis types
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - version-dependent
-    AxisType = None
+__all__ = ["make_host_mesh", "make_production_mesh", "set_mesh", "shard_map"]
 
 
 def _axis_kwargs(n_axes: int) -> dict:
-    if AxisType is None:
-        return {}
     return {"axis_types": (AxisType.Auto,) * n_axes}
-
-
-def shard_map(fn, **kwargs):
-    """Version-portable ``shard_map``: top-level ``jax.shard_map`` (jax >=
-    0.6, replication check spelled ``check_vma``) or the experimental home
-    (``check_rep``) on older versions."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, **kwargs)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    if "check_vma" in kwargs:
-        kwargs["check_rep"] = kwargs.pop("check_vma")
-    return _shard_map(fn, **kwargs)
-
-
-def set_mesh(mesh):
-    """Context manager installing ``mesh`` as the ambient mesh.
-
-    ``jax.sharding.set_mesh`` where it exists; on older jax the mesh object
-    itself is the context manager."""
-    if hasattr(jax.sharding, "set_mesh"):
-        return jax.sharding.set_mesh(mesh)
-    return mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -16,6 +16,7 @@ import numpy as np
 from ..configs import ARCH_IDS, get, get_smoke
 from ..models.lm import LM
 from ..runtime.serve_loop import Request, serve_requests
+from .env import enable_compile_cache
 
 
 def main() -> None:
@@ -28,6 +29,7 @@ def main() -> None:
     ap.add_argument("--max-seq", type=int, default=128)
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_smoke(args.arch) if args.smoke else get(args.arch)
     if not cfg.causal:
         raise SystemExit(f"{cfg.name} is encoder-only: no decode serving")

@@ -78,9 +78,10 @@ def main() -> None:
     # Tuned env for everything forked from here (shard-executor workers,
     # remote worker spawns). LD_PRELOAD/XLA pinning for *this* process must
     # come from the wrapper: python -m repro.launch.env -- python -m ...
-    from .env import apply as apply_tuned_env
+    from .env import apply as apply_tuned_env, enable_compile_cache
 
     apply_tuned_env()
+    enable_compile_cache()
 
     cfg = get_smoke(args.arch) if args.smoke else get(args.arch)
     mesh = (

@@ -168,10 +168,7 @@ def _moe_ep_body(p: dict, x: jax.Array, cfg, model_axis: str, data_axes: tuple[s
     """Per-device body under shard_map. x: (b_loc, s, d); expert weights are
     the local expert slice (E_loc, ...)."""
     m = cfg.moe
-    if hasattr(jax.lax, "axis_size"):
-        n_shards = jax.lax.axis_size(model_axis)
-    else:  # older jax: count the axis by reducing a 1 over it
-        n_shards = jax.lax.psum(1, model_axis)
+    n_shards = jax.lax.axis_size(model_axis)
     e_loc = m.n_experts // n_shards
     b, s, d = x.shape
     xf = x.reshape(-1, d)
@@ -236,15 +233,10 @@ def moe_ep(p: dict, x: jax.Array, cfg, mesh, data_axes: tuple[str, ...], model_a
         "w_down": P(model_axis, None, None),
     }
     pp = {k: p[k] for k in param_specs}
-    if hasattr(jax, "shard_map"):  # jax >= 0.6
-        smap = partial(jax.shard_map, check_vma=False)
-    else:  # older jax: experimental home, check flag spelled check_rep
-        from jax.experimental.shard_map import shard_map as _shard_map
-
-        smap = partial(_shard_map, check_rep=False)
-    return smap(
+    return jax.shard_map(
         body,
         mesh=mesh,
+        check_vma=False,
         in_specs=(param_specs, P(dp, None, None)),
         out_specs=(P(dp, None, None), P()),
     )(pp, x)

@@ -19,11 +19,7 @@ from typing import Any
 
 import jax
 import numpy as np
-
-try:  # jax >= 0.4.38; older versions predate explicit axis types
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - version-dependent
-    AxisType = None
+from jax.sharding import AxisType
 
 from ..distributed.sharding import DEFAULT_RULES, tree_shardings
 
@@ -35,11 +31,9 @@ def available_mesh(model_parallel: int = 1, devices=None):
     mp = model_parallel
     while n % mp:
         mp -= 1
-    kwargs = {}
-    if AxisType is not None:
-        kwargs["axis_types"] = (AxisType.Auto, AxisType.Auto)
     return jax.make_mesh(
-        (n // mp, mp), ("data", "model"), devices=devices, **kwargs
+        (n // mp, mp), ("data", "model"), devices=devices,
+        axis_types=(AxisType.Auto, AxisType.Auto),
     )
 
 

@@ -497,6 +497,42 @@ def test_two_pass_fit_vocab_matches_whole_frame(tmp_path, records):
         assert vocab.itos == vocab_whole.itos
 
 
+def test_full_dedup_streams_through_processes_when_asked(tmp_path):
+    """A full-subset drop_duplicates needs cross-shard state in one pass,
+    which only the thread executor holds. A run that explicitly asks for
+    worker processes takes the two-pass protocol instead and really runs
+    on them, with the same vocabulary and batch stream as threads."""
+    d = write_shards(tmp_path, EDGE_RECORDS * 3, n_files=4)
+
+    def base():
+        return (
+            Dataset.from_json_dirs([d], FIELDS)
+            .dropna(FIELDS)
+            .drop_duplicates()
+            .apply(*case_study_stages())
+        )
+
+    runs = {}
+    for executor in ("thread", "process"):
+        fit_stats: dict = {}
+        tok = base().fit_vocab(
+            vocab_size=64, workers=2, executor=executor, stats=fit_stats
+        )
+        assert fit_stats["executor"] == executor
+        assert fit_stats["two_pass"] is (executor == "process")
+        stream_stats: dict = {}
+        rows = batch_rows(
+            base()
+            .tokenize(tok, seq2seq_specs(max_abstract_len=16, max_title_len=8))
+            .batch(4, shuffle=False, drop_remainder=False)
+            .prefetch(2)
+            .iter_batches(workers=2, executor=executor, stats=stream_stats)
+        )
+        assert stream_stats["executor"] == executor
+        runs[executor] = (tok.itos, rows)
+    assert runs["process"] == runs["thread"]
+
+
 def test_dedup_plan_thread_matches_whole_frame(tmp_path):
     records = EDGE_RECORDS + EDGE_RECORDS  # every row duplicated across shards
     d = write_shards(tmp_path, records)
@@ -688,6 +724,41 @@ def test_backend_resolution_and_validation(tmp_path, monkeypatch):
     ds = chain(d).backend("fused")
     assert ds.plan == chain(d).plan
     assert "bytes backend: fused" in ds.explain()
+
+
+@pytest.mark.parametrize("backend", ["loops", "fused", "pallas"])
+def test_worker_processes_get_a_host_backend(tmp_path, monkeypatch, backend):
+    """The parent decides when it compiles a program what its worker
+    processes run: the pallas backend's device offload stays with the
+    parent, and the process and remote executors ship the byte-identical
+    host form, so no worker ever imports jax or reaches for the chip."""
+    from repro.distributed.coordinator import RemoteShardExecutor
+
+    d = write_shards(tmp_path, EDGE_RECORDS)
+    ds = chain(d)
+    frame_nodes, _ = P.split_plan(ds.plan)
+    program = EX.compile_shard_program(
+        P.optimize_plan(frame_nodes, ds.schema), optimize=True, backend=backend
+    )
+    host = "fused" if backend == "pallas" else backend
+    assert program.backend == backend
+    assert program.worker_backend == host
+    assert program.for_workers().backend == host
+    shards = ing.list_shards([d])
+    shipped = []
+    for_workers = EX.ShardProgram.for_workers
+    monkeypatch.setattr(
+        EX.ShardProgram, "for_workers",
+        lambda self: shipped.append(for_workers(self)) or shipped[-1],
+    )
+    proc = EX.ProcessShardExecutor(shards, program, workers=2)
+    proc.stop()
+    assert [p.backend for p in shipped] == [host]
+    remote = RemoteShardExecutor(shards, program, workers=1, remote={"spawn": False})
+    try:
+        assert remote._coord.program.backend == host
+    finally:
+        remote.stop()
 
 
 # ---------------------------------------------------------------------------

@@ -3,26 +3,14 @@ shapes and dtypes.
 
 Every parity test here runs under ``interpret=True`` so the kernel bodies
 execute on CPU in plain CI — no blanket skip. The only genuinely-TPU-only
-cases are the *compiled* (non-interpret) runs, and those are gated by a
-capability check (``requires_tpu``) instead of skipping the module."""
+cases are the *compiled* (non-interpret) runs, and those take the ``tpu``
+fixture, which asks for the backend when the test runs — never while the
+module is collected."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-
-
-def tpu_available() -> bool:
-    try:
-        return len(jax.devices("tpu")) > 0
-    except RuntimeError:
-        return False
-
-
-requires_tpu = pytest.mark.skipif(
-    not tpu_available(),
-    reason="compiled (non-interpret) Pallas kernels need a TPU backend",
-)
 
 from repro.kernels.flash_attention.ops import flash_attention_op
 from repro.kernels.flash_attention.ref import flash_attention_ref
@@ -34,6 +22,15 @@ from repro.kernels.text_clean.ops import clean_rows, pack_rows, text_clean_op
 from repro.kernels.text_clean.ref import text_clean_ref
 
 KEY = jax.random.PRNGKey(0)
+
+
+@pytest.fixture
+def tpu():
+    """Skip unless JAX's backend is a TPU (compiled Mosaic kernels)."""
+    from repro.kernels.pallas_compat import has_tpu
+
+    if not has_tpu():
+        pytest.skip("compiled (non-interpret) Pallas kernels need a TPU backend")
 
 
 def tol(dtype):
@@ -188,19 +185,17 @@ def test_text_clean_vs_ref(blk):
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
 
 
-@requires_tpu
 @pytest.mark.parametrize("blk", [64])
-def test_text_clean_compiled_on_tpu(blk):
+def test_text_clean_compiled_on_tpu(tpu, blk):
     """Same parity as above but Mosaic-compiled — TPU capability gated."""
     rows = ["Hello <b>World</b> 42!", "plain text only", ""] * 11
-    mat = pack_rows(rows)
+    mat = pack_rows(rows, width=128)
     out = text_clean_op(mat, blk_rows=blk, interpret=False)
     ref = text_clean_ref(mat)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
 
 
-@requires_tpu
-def test_flash_attention_compiled_on_tpu():
+def test_flash_attention_compiled_on_tpu(tpu):
     b, s, h, hd = 1, 128, 4, 64
     ks = jax.random.split(KEY, 3)
     q = jax.random.normal(ks[0], (b, s, h, hd))
@@ -243,12 +238,11 @@ def test_text_scan_vs_ref(flags):
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
 
 
-@requires_tpu
-def test_text_scan_compiled_on_tpu():
+def test_text_scan_compiled_on_tpu(tpu):
     from repro.kernels.text_clean.ops import text_scan_op
     from repro.kernels.text_clean.ref import text_scan_ref
 
-    mat = pack_rows(SCAN_ROWS)
+    mat = pack_rows(SCAN_ROWS, width=128)
     out = text_scan_op(mat, lower=True, strip_html=True, strip_parens=True,
                        interpret=False)
     ref = text_scan_ref(mat, lower=True, strip_html=True, strip_parens=True)
@@ -301,3 +295,45 @@ def test_text_clean_matches_host_stages():
         buf = B.collapse_spaces(buf)
         expect.append(B.unflatten(buf)[0])
     assert out == expect
+
+
+def test_scan_flat_shapes_are_bounded():
+    """``scan_flat`` pads onto a small shape ladder: lane-aligned widths
+    under 2x, row counts under 2x in whole row blocks, and a handful of
+    distinct shapes per width for every row count up to 20k."""
+    from repro.kernels.text_clean.ops import padded_shape
+    from repro.kernels.text_clean.text_clean import LANE_TILE, ROW_TILE, block_rows
+
+    rows_per_width: dict[int, set] = {}
+    for n in range(1, 20001, 3):
+        for w in (1, 100, 129, 1738, 5000):
+            r, wp = padded_shape(n, w)
+            assert wp >= w and wp % LANE_TILE == 0 and wp < 2 * max(w, LANE_TILE)
+            assert n <= r <= 2 * max(n, ROW_TILE) and r % ROW_TILE == 0
+            assert r % min(block_rows(wp), r) == 0
+            rows_per_width.setdefault(w, set()).add(r)
+    assert max(len(rs) for rs in rows_per_width.values()) <= 20
+
+
+@pytest.mark.parametrize("interpret_env", [True, False])
+def test_pallas_backend_counts_kernel_calls(monkeypatch, interpret_env):
+    """``execute_ops(..., "pallas", stats=...)`` counts each scan pass the
+    kernel ran and each the bridge declined; the bytes equal the loops
+    backend's either way. Off-TPU the bridge declines unless interpret
+    mode is forced."""
+    from repro.core import bytesops as B
+    from repro.core import expr as E
+
+    if interpret_env:
+        monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
+    else:
+        monkeypatch.delenv("REPRO_PALLAS_INTERPRET", raising=False)
+    ops = list(E.compile_expr(E.abstract_expr())[2])
+    buf = B.flatten(SCAN_ROWS + ["Don't <i>STOP</i> (ever) now"])
+    stats: dict = {}
+    got = B.execute_ops(buf, ops, "pallas", stats=stats)
+    np.testing.assert_array_equal(got, B.execute_ops(buf, ops, "loops"))
+    if interpret_env:
+        assert stats.get("pallas_calls", 0) >= 1 and "pallas_declines" not in stats
+    else:
+        assert stats.get("pallas_declines", 0) >= 1 and "pallas_calls" not in stats

@@ -94,3 +94,29 @@ def test_main_prints_exports(capsys, monkeypatch):
     # shlex.quote leaves the flag bare (no shell-special characters)
     assert "export XLA_FLAGS=--xla_force_host_platform_device_count=16" in out
     assert "export TF_CPP_MIN_LOG_LEVEL=4" in out
+
+
+@pytest.mark.parametrize("configured", [True, False])
+def test_compile_cache_dir_is_fixed_or_configured(tmp_path, monkeypatch, configured):
+    """With JAX_COMPILATION_CACHE_DIR set the helper leaves JAX's own
+    reading of it alone; otherwise the cache goes to one fixed directory
+    inside the checkout, the same on every call."""
+    import jax
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        if configured:
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+            jax.config.update("jax_compilation_cache_dir", None)
+            assert launch_env.enable_compile_cache() == str(tmp_path)
+            assert jax.config.jax_compilation_cache_dir is None
+        else:
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+            first = launch_env.enable_compile_cache()
+            assert first == launch_env.enable_compile_cache()
+            assert jax.config.jax_compilation_cache_dir == first
+            root = launch_env.DEFAULT_COMPILE_CACHE.parent
+            assert first == str(root / ".jax_cache")
+            assert (root / "pyproject.toml").exists()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
