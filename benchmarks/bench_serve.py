@@ -100,7 +100,7 @@ def run(quick: bool = False, requests: int | None = None, slots: int = 4) -> dic
     wall_s = time.perf_counter() - t0
 
     lat_ms = sorted(v * 1e3 for v in stats.latency_s.values())
-    host_s = stats.preprocess_s + stats.decode_s
+    host_s = stats.preprocess_s + stats.prefill_s + stats.decode_s
     return {
         "name": "serve_latency",
         "quick": quick,
@@ -114,7 +114,9 @@ def run(quick: bool = False, requests: int | None = None, slots: int = 4) -> dic
         "p50_ms": round(float(np.percentile(lat_ms, 50)), 3),
         "p99_ms": round(float(np.percentile(lat_ms, 99)), 3),
         "preprocess_s": round(stats.preprocess_s, 4),
+        "prefill_s": round(stats.prefill_s, 4),
         "decode_s": round(stats.decode_s, 4),
+        "compile_s": round(stats.compile_s, 4),
         "preprocess_frac": round(stats.preprocess_s / host_s, 5) if host_s else 0.0,
         "tokens_generated": tokens_generated,
         "requests_per_s": round(len(reqs) / wall_s, 2) if wall_s else 0.0,

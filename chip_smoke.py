@@ -160,7 +160,8 @@ def phase_serve(args, corpus: Path, held_out: Path, run):
     wall = time.perf_counter() - t0
     print(f"serve: {len(results)}/{len(reqs)} answered in {wall!r} s; served={stats.served} "
           f"filtered={stats.filtered} cache_hits={stats.cache_hits} "
-          f"preprocess_s={stats.preprocess_s!r} decode_s={stats.decode_s!r}")
+          f"preprocess_s={stats.preprocess_s!r} prefill_s={stats.prefill_s!r} "
+          f"decode_s={stats.decode_s!r} compiles={stats.compiles} compile_s={stats.compile_s!r}")
     if len(results) != len(reqs):
         return f"answered {len(results)} of {len(reqs)} requests"
     empty, repeat = len(texts) - 2, len(texts) - 1

@@ -98,7 +98,9 @@ def main() -> None:
         f"served {stats.served}/{len(reqs)} through {args.slots} slots: "
         f"{stats.filtered} filtered, {stats.cache_hits} cache hit(s), "
         f"preprocess {stats.preprocess_s * 1e3:.1f} ms / "
-        f"decode {stats.decode_s * 1e3:.1f} ms"
+        f"prefill {stats.prefill_s * 1e3:.1f} ms / "
+        f"decode {stats.decode_s * 1e3:.1f} ms / "
+        f"compile {stats.compile_s * 1e3:.1f} ms ({stats.compiles} programs)"
     )
     assert len(results) == len(reqs)
     assert stats.cache_hits >= 1 and results[len(texts) - 1] == results[0]

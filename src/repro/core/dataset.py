@@ -858,7 +858,7 @@ class Dataset:
         snap onto the plan's fixed bucket grid, transfers double-buffer
         ahead of compute, and the feed's :class:`OverlapProfiler` accounts
         device-idle time per step. ``stats`` is passed to
-        :meth:`iter_batches`."""
+        :meth:`iter_batches`, and the feed adds its ``transfer_s``."""
         self._require_valid(optimize=optimize)
         node = next((n for n in self._nodes if isinstance(n, P.Prefetch)), None)
         depth = prefetch if prefetch is not None else (node.prefetch if node else 2)
@@ -876,6 +876,7 @@ class Dataset:
                 prefetch=depth,
                 sharding=shard,
                 profiler=profiler,
+                stats=stats,
             )
         return AsyncLoader(it, prefetch=depth, sharding=shard)
 

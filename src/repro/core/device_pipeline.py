@@ -35,6 +35,7 @@ from typing import Any, Callable, Iterator, Mapping, Sequence
 import numpy as np
 
 from ..data.tokenizer import PAD
+from ..spans import span
 from .async_loader import AsyncLoader, LoaderStats
 
 # ---------------------------------------------------------------------------
@@ -214,9 +215,6 @@ class OverlapProfiler:
         if dt > self.starvation_eps:
             self._r.starved_steps += 1
 
-    def record_transfer(self, dt: float) -> None:
-        self._r.transfer_s += dt
-
     @contextmanager
     def step(self):
         """Time one device-compute segment (caller blocks on the result
@@ -251,6 +249,11 @@ class DeviceFeed:
     and, when ``donate=True`` (default), marks the batch consumed so the
     donating jit'd step (``donate_argnums``) can never observe a stale
     read.
+
+    The wait for each host batch is the ``feed.wait`` span and each
+    batch's ``device_put`` calls the ``feed.transfer`` span, counted in
+    the report's ``host_wait_s`` and ``transfer_s``. ``stats`` (the plan's
+    stats dict, when given) also receives the running ``transfer_s``.
     """
 
     def __init__(
@@ -264,8 +267,10 @@ class DeviceFeed:
         device_put: Callable[[np.ndarray], Any] | None = None,
         clock: Callable[[], float] = time.perf_counter,
         profiler: OverlapProfiler | None = None,
+        stats: dict | None = None,
     ):
         self.grid = grid
+        self._stats = stats
         self.donate = donate
         self._sharding = sharding
         self._device_put = device_put
@@ -314,9 +319,11 @@ class DeviceFeed:
             if self.grid is not None
             else tuple(sorted((k, np.shape(v)) for k, v in snapped.items()))
         )
-        t0 = self._clock()
-        arrays = {k: self._put_leaf(np.asarray(v)) for k, v in snapped.items()}
-        self.profiler.record_transfer(self._clock() - t0)
+        report = self.profiler.report()
+        with span("feed.transfer", report, "transfer_s", clock=self._clock):
+            arrays = {k: self._put_leaf(np.asarray(v)) for k, v in snapped.items()}
+        if self._stats is not None:
+            self._stats["transfer_s"] = report.transfer_s
         return DeviceBatch(arrays, cell)
 
     # -- consumption -------------------------------------------------------
@@ -324,12 +331,12 @@ class DeviceFeed:
         pending: DeviceBatch | None = None
         first = True
         while True:
-            t0 = self._clock()
             try:
-                host = next(self._source)
+                with span("feed.wait", clock=self._clock) as waited:
+                    host = next(self._source)
             except StopIteration:
                 break
-            self.profiler.record_wait(self._clock() - t0, startup=first)
+            self.profiler.record_wait(waited.seconds, startup=first)
             first = False
             nxt = self._transfer(host)
             if pending is not None:

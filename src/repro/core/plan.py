@@ -53,6 +53,7 @@ from ..data.batching import (
     pad_batch,
     split_indices,
 )
+from ..spans import span
 from . import expr as E
 from . import ingest as ing
 from .frame import ColumnarFrame
@@ -944,7 +945,9 @@ def stream_batches(
     :class:`repro.distributed.coordinator.RemoteShardExecutor`). When
     ``stats`` is a dict it receives
     ``executor``, ``cache_hits``, ``cache_misses`` and per-epoch ``timings``
-    after each epoch completes.
+    after each epoch completes, and ``epochs`` and ``epoch_start_s`` (the
+    ``plan.epoch_start`` spans: each epoch's executor start to its first
+    batch) as each epoch yields its first batch.
     """
     from ..analysis import PlanValidationError, check_streaming_plan
     from . import executor as EX
@@ -1032,6 +1035,20 @@ def stream_batches(
 
     epoch = 0
     while epochs is None or epoch < epochs:
+        # From the executor's start to the epoch's first batch: worker
+        # start-up and the shuffle buffer's refill, which the consumer waits
+        # out at every epoch boundary.
+        opening: span | None = span("plan.epoch_start", stats, "epoch_start_s")
+        opening.__enter__()
+
+        def opened() -> None:
+            nonlocal opening
+            if opening is not None:
+                opening.__exit__(None, None, None)
+                opening = None
+                if stats is not None:
+                    stats["epochs"] = stats.get("epochs", 0) + 1
+
         exec_ = EX.make_executor(
             shards,
             program,
@@ -1065,9 +1082,11 @@ def stream_batches(
         produced = 0
         try:
             for b in _batched(chunks(), batch, rng, buffer):
+                opened()
                 produced += 1
                 yield b
         finally:
+            opened()
             # Abandoned mid-epoch (consumer broke out / AsyncLoader closed):
             # stop the workers instead of preprocessing the rest of the
             # corpus into a queue nobody drains.

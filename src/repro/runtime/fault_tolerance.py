@@ -24,6 +24,8 @@ import time
 from pathlib import Path
 from typing import Any, Callable, Iterator
 
+from ..spans import span
+
 # NOTE: Checkpointer (and through it jax) is imported lazily inside
 # TrainController.__init__. The distributed preprocessing workers import
 # this module for Heartbeat, and the worker tier must stay jax-free at
@@ -76,7 +78,12 @@ class Heartbeat:
 
 
 class TrainController:
-    """Checkpointed step loop: resumes from the latest committed step."""
+    """Checkpointed step loop: resumes from the latest committed step.
+
+    ``stats`` counts the steps :meth:`run` took and ``sync_s``, the time
+    spent turning each step's metrics into floats (the ``train.sync``
+    spans): the loop's wait for the device to finish the step.
+    """
 
     def __init__(
         self,
@@ -96,6 +103,7 @@ class TrainController:
         self.save_every = save_every
         self.heartbeat = heartbeat
         self.shardings = shardings
+        self.stats = {"steps": 0, "sync_s": 0.0}
 
         latest = self.ckpt.latest()
         if latest is None:
@@ -121,7 +129,10 @@ class TrainController:
             self.step += 1
             if self.heartbeat is not None:
                 self.heartbeat.beat(self.step)
-            history.append({"step": self.step, **{k: float(v) for k, v in metrics.items()}})
+            with span("train.sync", self.stats, "sync_s"):
+                scalars = {k: float(v) for k, v in metrics.items()}
+            self.stats["steps"] += 1
+            history.append({"step": self.step, **scalars})
             if self.step % self.save_every == 0:
                 self.save()
         self.save()

@@ -21,7 +21,13 @@ Layers, bottom up:
   token lists out, with an :class:`AdmissionQueue`, per-request
   preprocessing through the row program, ring-cache hits, and a
   :class:`ServeStats` ledger (admission/shed/filter counters, cache
-  accounting, preprocess-vs-decode time split, per-request latency).
+  accounting, the row program's, prefill's, decode steps' and compiles'
+  time, per-request latency, first-token time and token gaps).
+
+The row program, each prefill and each decode step run in program spans
+(``serve.row_program``, ``serve.prefill``, ``serve.decode_step``, see
+:mod:`repro.spans`): they land on a profiler trace and add their time to
+the ledger.
 
 Contract (linter rule R005): this module is the serve hot path — it must
 never import the shard/shm/pool machinery (``core.executor``,
@@ -40,6 +46,8 @@ from typing import Any, Callable, Mapping, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from ..spans import span
 
 PAD_ID = 0
 
@@ -152,9 +160,19 @@ class RingCache:
 
 @dataclass
 class ServeStats:
-    """One serve run's ledger: admission/shed/filter counters, ring-cache
-    accounting, the preprocess-vs-decode wall-time split, and per-request
-    end-to-end latency (admission offer -> final token)."""
+    """One serve run's ledger, which may span several ``serve_text`` calls.
+
+    Counters: admission, shed, filter, served, ring-cache hits and misses.
+    Time, each the sum of its spans: ``preprocess_s`` the row program
+    (``serve.row_program``), ``prefill_s`` each prompt's prefill through its
+    first token on the host (``serve.prefill``), ``decode_s`` each decode
+    step through its token on the host (``serve.decode_step``).
+    ``compiles`` and ``compile_s``: the programs traced, lowered, and
+    compiled or loaded from the compilation cache while a call ran, and
+    their time. Per request (by uid): ``latency_s`` from the admission
+    offer to the final token, ``first_token_s`` from the offer to the
+    first token; ``token_gaps_s`` holds, for every later token, its gap
+    since the same request's previous token."""
 
     admitted: int = 0
     rejected: int = 0
@@ -163,8 +181,52 @@ class ServeStats:
     cache_hits: int = 0
     cache_misses: int = 0
     preprocess_s: float = 0.0
+    prefill_s: float = 0.0
     decode_s: float = 0.0
+    compiles: int = 0
+    compile_s: float = 0.0
     latency_s: dict[int, float] = field(default_factory=dict)
+    first_token_s: dict[int, float] = field(default_factory=dict)
+    token_gaps_s: list[float] = field(default_factory=list)
+
+
+# The compile events of jax.monitoring, as their time spans. Traces nest:
+# jitted functions called inside another (jnp's own among them) trace inside
+# its trace, and a few trace again inside its lowering. So a span that holds
+# spans already counted adds only the time outside them, and ``compile_s``
+# is the union of the spans. The backend compile holds the persistent-cache
+# read, so a cache load counts once, as one of ``compiles``.
+_COMPILE_EVENTS = (
+    "/jax/core/compile/jaxpr_trace_duration",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration",
+    "/jax/core/compile/backend_compile_duration",
+)
+
+
+class _CompileLedger(threading.local):
+    """Adds JAX's compile events to the ServeStats of the ``serve_text``
+    call running on the thread that compiles (``stats``; None outside a
+    call). ``jax.monitoring`` listeners are process-wide, so one listener,
+    registered at import, serves every call."""
+
+    def __init__(self):
+        self.stats: ServeStats | None = None
+        self.spans: list[tuple[float, float]] = []  # outermost spans counted
+
+    def event(self, event: str, start: float, end: float, **_kw) -> None:
+        st = self.stats
+        if st is None or event not in _COMPILE_EVENTS:
+            return
+        inner = [(s, e) for s, e in self.spans if start <= s and e <= end]
+        if inner:
+            self.spans = [x for x in self.spans if x not in inner]
+        self.spans.append((start, end))
+        st.compile_s += (end - start) - sum(e - s for s, e in inner)
+        st.compiles += event == _COMPILE_EVENTS[2]
+
+
+_compile_ledger = _CompileLedger()
+jax.monitoring.register_event_time_span_listener(_compile_ledger.event)
 
 
 def _continuous_decode(
@@ -177,12 +239,17 @@ def _continuous_decode(
     max_seq: int = 128,
     eos_id: int = 2,
     cache_dtype=jnp.float32,
+    stats: ServeStats | None = None,
+    on_token: Callable[[int, int], None] | None = None,
 ) -> None:
     """The continuous-batching slot driver: fixed decode slots; a finished
     slot refills immediately from ``next_item`` (block prefill, one slot at
     a time, per-slot position tracking). ``next_item`` returns
     ``(uid, prompt, max_new)`` or None when drained; ``on_done`` receives
-    each request's generated tokens."""
+    each request's generated tokens. Each prefill and decode step is a
+    span whose time goes to ``stats.prefill_s`` / ``stats.decode_s`` when
+    ``stats`` is given; ``on_token(uid, index)`` is called as each token
+    reaches the host (index 0 is the prefill's)."""
     step = jax.jit(make_serve_step(model))
     prefill = jax.jit(model.decode_step)
 
@@ -198,10 +265,13 @@ def _continuous_decode(
             return
         uid, prompt, max_new = item
         states[slot] = model.init_decode_state(1, max_seq, cache_dtype)
-        logits, states[slot] = prefill(
-            params, jnp.asarray(prompt[None]), states[slot], jnp.int32(0)
-        )
-        nxt = int(jnp.argmax(logits[0, -1]))
+        with span("serve.prefill", stats, "prefill_s"):
+            logits, states[slot] = prefill(
+                params, jnp.asarray(prompt[None]), states[slot], jnp.int32(0)
+            )
+            nxt = int(jnp.argmax(logits[0, -1]))
+        if on_token is not None:
+            on_token(uid, 0)
         active[slot] = {"uid": uid, "max_new": max_new, "pos": len(prompt), "out": [nxt]}
         last_tok[slot] = nxt
 
@@ -222,9 +292,12 @@ def _continuous_decode(
                 on_done(a["uid"], a["out"])
                 fill(s)
                 continue
-            toks = jnp.full((1, 1), last_tok[s], jnp.int32)
-            nxt, _, states[s] = step(params, toks, states[s], jnp.int32(a["pos"]))
-            last_tok[s] = int(nxt[0, 0])
+            with span("serve.decode_step", stats, "decode_s"):
+                toks = jnp.full((1, 1), last_tok[s], jnp.int32)
+                nxt, _, states[s] = step(params, toks, states[s], jnp.int32(a["pos"]))
+                last_tok[s] = int(nxt[0, 0])
+            if on_token is not None:
+                on_token(a["uid"], len(a["out"]))
             a["out"].append(last_tok[s])
             a["pos"] += 1
 
@@ -305,8 +378,9 @@ def serve_text(
     filters out — or that encodes to an empty prompt — is answered with
     ``[]`` and counted in ``stats.filtered``; it never occupies a slot.
 
-    ``stats`` (a :class:`ServeStats`) receives counters, the
-    preprocess-vs-decode time split, and per-uid end-to-end latency.
+    ``stats`` (a :class:`ServeStats`) receives counters, the time of the
+    row program, prefills, decode steps and compiles, and per-uid latency,
+    first-token time and token gaps.
     """
     st = stats if stats is not None else ServeStats()
     out_name = prompt_output or row_program.output_names[0]
@@ -314,7 +388,7 @@ def serve_text(
     results: dict[int, list[int]] = {}
     offered_at: dict[int, float] = {}
     keys: dict[int, tuple] = {}
-    t_start = time.perf_counter()
+    token_at: dict[int, float] = {}
 
     for req in requests:
         key = _cache_key(row_program, req.text)
@@ -340,9 +414,8 @@ def serve_text(
             req = queue.pop()
             if req is None:
                 return None
-            t0 = time.perf_counter()
-            encoded = row_program(req.text)
-            st.preprocess_s += time.perf_counter() - t0
+            with span("serve.row_program", st, "preprocess_s"):
+                encoded = row_program(req.text)
             prompt = None if encoded is None else encoded[out_name][0]
             if prompt is not None:
                 prompt = prompt[prompt != PAD_ID][: max_seq - 1]
@@ -355,6 +428,14 @@ def serve_text(
                 continue
             return req.uid, np.asarray(prompt, dtype=np.int32), req.max_new
 
+    def on_token(uid: int, index: int) -> None:
+        now = time.perf_counter()
+        if index == 0:
+            st.first_token_s[uid] = now - offered_at[uid]
+        else:
+            st.token_gaps_s.append(now - token_at[uid])
+        token_at[uid] = now
+
     def on_done(uid: int, out: list[int]) -> None:
         results[uid] = out
         st.served += 1
@@ -362,15 +443,20 @@ def serve_text(
         if cache is not None:
             cache.put(keys[uid], out)
 
-    _continuous_decode(
-        model,
-        params,
-        next_item,
-        on_done,
-        slots=slots,
-        max_seq=max_seq,
-        eos_id=eos_id,
-        cache_dtype=cache_dtype,
-    )
-    st.decode_s += (time.perf_counter() - t_start) - st.preprocess_s
+    _compile_ledger.stats, _compile_ledger.spans = st, []
+    try:
+        _continuous_decode(
+            model,
+            params,
+            next_item,
+            on_done,
+            slots=slots,
+            max_seq=max_seq,
+            eos_id=eos_id,
+            cache_dtype=cache_dtype,
+            stats=st,
+            on_token=on_token,
+        )
+    finally:
+        _compile_ledger.stats = None
     return results

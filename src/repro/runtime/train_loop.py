@@ -62,16 +62,19 @@ def make_input_pipeline(
     call ``.close()`` (or let a ``finally`` do it) when training stops
     mid-epoch so remote workers shut down instead of preprocessing into a
     queue nobody drains. ``stats`` (a dict) receives executor and cache
-    counters after each epoch.
+    counters after each epoch, and the epochs' start times.
 
     ``overlap=True`` (or passing a ``profiler``) upgrades the tail to a
     :class:`~repro.core.device_pipeline.DeviceFeed`: batches snap onto the
     plan's fixed bucket grid (the jit'd step compiles once per grid cell),
     transfers double-buffer one batch ahead, the consuming step donates
     its input buffers (``donate``), and the feed's
-    :class:`~repro.core.device_pipeline.OverlapProfiler` accounts
-    host-wait vs device-compute time into a device-idle fraction — wrap
-    each step in ``feed.step(batch)`` to attribute its compute segment.
+    :class:`~repro.core.device_pipeline.OverlapProfiler` counts the
+    step loop's wait for host batches (``feed.wait``) and the transfers
+    (``feed.transfer``). The step's own wait for the device is counted
+    where the loop reads the step's results: a loop driven by
+    :class:`~repro.runtime.fault_tolerance.TrainController` finds it in
+    ``TrainController.stats`` (the ``train.sync`` spans).
     """
     from ..core.async_loader import AsyncLoader
 
@@ -86,6 +89,7 @@ def make_input_pipeline(
             sharding=sharding,
             donate=donate,
             profiler=profiler,
+            stats=stats,
         )
     return AsyncLoader(batches, prefetch=prefetch, sharding=sharding)
 

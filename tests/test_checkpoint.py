@@ -143,3 +143,25 @@ def test_elastic_remesh_roundtrip(tmp_path):
     mesh = available_mesh(model_parallel=1)
     out = remesh(tree, axes, mesh)
     assert tree_eq(tree, out)
+
+
+def test_controller_stats_count_steps_and_sync(tmp_path):
+    """``TrainController.stats`` counts the steps run and the time turning
+    their metrics into floats; the history is what it was without them."""
+
+    def init_state():
+        return {"w": jnp.zeros(2)}, {"m": jnp.zeros(2)}
+
+    def step(params, opt, batch):
+        params = jax.tree.map(lambda w: w + batch, params)
+        return params, opt, {"loss": params["w"][0] * 2.0, "grad_norm": jnp.asarray(0.5)}
+
+    c = TrainController(tmp_path, step, init_state, save_every=100)
+    history = c.run(iter([1.0, 2.0, 3.0]), n_steps=10)
+    assert history == [
+        {"step": 1, "loss": 2.0, "grad_norm": 0.5},
+        {"step": 2, "loss": 6.0, "grad_norm": 0.5},
+        {"step": 3, "loss": 12.0, "grad_norm": 0.5},
+    ]
+    assert c.stats["steps"] == 3
+    assert c.stats["sync_s"] > 0.0

@@ -336,3 +336,46 @@ def test_dataset_device_batches_overlap_terminal(corpus):
     finally:
         feed.close()
     assert n > 0
+
+
+def test_feed_spans_count_waits_and_transfers_under_fake_clock():
+    """``feed.wait`` and ``feed.transfer`` count on the feed's clock, and a
+    stats dict given to the feed receives the running ``transfer_s``."""
+    clock = FakeClock()
+
+    def src(n=3):
+        for i in range(n):
+            clock.advance(2.0)
+            yield _batch(i)
+
+    def put(x):
+        clock.advance(0.5)  # one leaf's transfer
+        return x
+
+    stats: dict = {}
+    feed = DeviceFeed(src(), prefetch=0, device_put=put, clock=clock, stats=stats)
+    assert sum(1 for _ in feed) == 3
+    r = feed.report()
+    assert (r.startup_s, r.host_wait_s) == (pytest.approx(2.0), pytest.approx(4.0))
+    assert r.transfer_s == pytest.approx(1.5)
+    assert stats == {"transfer_s": pytest.approx(1.5)}
+
+
+def test_stream_batches_counts_epoch_starts(corpus):
+    """Each epoch's executor start to its first batch is one
+    ``plan.epoch_start`` span in the plan's stats dict."""
+    from repro.core.dataset import Dataset
+    from repro.core.expr import abstract_expr, col
+
+    base = (
+        Dataset.from_json_dirs([corpus])
+        .where(col("abstract").not_empty())
+        .transform(abstract=abstract_expr())
+    )
+    tok = base.fit_vocab(vocab_size=300)
+    chain = base.tokenize(tok, col="abstract", max_len=16).batch(4, shuffle=False).prefetch(2)
+    stats: dict = {}
+    once = sum(1 for _ in chain.iter_batches(epochs=1))
+    assert sum(1 for _ in chain.iter_batches(epochs=2, stats=stats)) == 2 * once
+    assert stats["epochs"] == 2
+    assert stats["epoch_start_s"] > 0.0

@@ -197,6 +197,37 @@ def test_serve_text_ring_cache_round_trip(row_program):
     assert stats.cache_misses == 3 and miss[9] == first[0]
 
 
+def test_serve_text_records_first_tokens_gaps_and_spans(row_program):
+    """The ledger's per-token hook: every served uid gets a first-token
+    time below its latency and ``len(out) - 1`` token gaps; prefill and
+    decode are measured spans, not a residual; the fresh per-call programs
+    count as compiles."""
+    rp, _ = row_program
+    stats = ServeStats()
+    for uid in range(3):  # one request per call: each call's gaps are its own
+        before = len(stats.token_gaps_s)
+        results = serve_text(
+            _EchoModel(), None, rp, [TextRequest(uid, CORPUS[uid]["abstract"], max_new=2 + uid)],
+            slots=2, max_seq=32, stats=stats,
+        )
+        assert len(results[uid]) == 2 + uid
+        assert len(stats.token_gaps_s) - before == len(results[uid]) - 1
+    assert sorted(stats.first_token_s) == [0, 1, 2]
+    assert all(0 < stats.first_token_s[u] <= stats.latency_s[u] for u in range(3))
+    assert all(g > 0 for g in stats.token_gaps_s)
+    assert stats.prefill_s > 0.0 and stats.decode_s > 0.0
+    assert stats.compiles >= 3 and stats.compile_s > 0.0
+    assert stats.prefill_s + stats.decode_s < sum(stats.latency_s.values())
+
+
+def test_serve_requests_decodes_as_before_without_a_ledger():
+    from repro.runtime.serve_loop import Request, serve_requests
+
+    reqs = [Request(i, jnp.asarray([5, 6, 7 + i], jnp.int32), max_new=3) for i in range(3)]
+    out = serve_requests(_EchoModel(), None, reqs, slots=2, max_seq=16)
+    assert out == {0: [7, 7, 7], 1: [8, 8, 8], 2: [9, 9, 9]}
+
+
 # -- end-to-end with a real smoke LM ---------------------------------------
 
 
