@@ -11,6 +11,8 @@ repeated prompts.
 Layers, bottom up:
 
 * ``make_serve_step`` — the one-token greedy decode step (jit'd).
+* ``_programs`` — the step and prefill programs, made once per model
+  instance on its first serve and reused by every later call on it.
 * ``_continuous_decode`` — the slot driver: fixed decode slots, refill on
   completion from a ``next_item`` callback (continuous batching in its
   simplest correct form, unchanged from the original loop).
@@ -169,7 +171,9 @@ class ServeStats:
     step through its token on the host (``serve.decode_step``).
     ``compiles`` and ``compile_s``: the programs traced, lowered, and
     compiled or loaded from the compilation cache while a call ran, and
-    their time. Per request (by uid): ``latency_s`` from the admission
+    their time. ``program_builds`` and ``program_reuses``: per call,
+    whether the model's step and prefill programs had to be made or were
+    found on the model. Per request (by uid): ``latency_s`` from the admission
     offer to the final token, ``first_token_s`` from the offer to the
     first token; ``token_gaps_s`` holds, for every later token, its gap
     since the same request's previous token."""
@@ -185,6 +189,8 @@ class ServeStats:
     decode_s: float = 0.0
     compiles: int = 0
     compile_s: float = 0.0
+    program_builds: int = 0
+    program_reuses: int = 0
     latency_s: dict[int, float] = field(default_factory=dict)
     first_token_s: dict[int, float] = field(default_factory=dict)
     token_gaps_s: list[float] = field(default_factory=list)
@@ -229,6 +235,25 @@ _compile_ledger = _CompileLedger()
 jax.monitoring.register_event_time_span_listener(_compile_ledger.event)
 
 
+def _programs(model, stats: ServeStats | None):
+    """The model's jitted decode step and prefill, made on its first serve
+    and kept on the instance, so every later call on it reuses them and
+    JAX's own cache (keyed on shapes and dtypes) serves each prompt length,
+    ``max_seq`` and ``cache_dtype`` it has seen. As an attribute they live
+    and die with the model (their closures hold it, so nothing outside the
+    model may hold them). ``params`` stay arguments, so one program serves
+    any weights of the same shapes."""
+    programs = getattr(model, "_serve_programs", None)
+    built = programs is None
+    if built:
+        programs = jax.jit(make_serve_step(model)), jax.jit(model.decode_step)
+        model._serve_programs = programs
+    if stats is not None:
+        stats.program_builds += built
+        stats.program_reuses += not built
+    return programs
+
+
 def _continuous_decode(
     model,
     params,
@@ -250,8 +275,7 @@ def _continuous_decode(
     span whose time goes to ``stats.prefill_s`` / ``stats.decode_s`` when
     ``stats`` is given; ``on_token(uid, index)`` is called as each token
     reaches the host (index 0 is the prefill's)."""
-    step = jax.jit(make_serve_step(model))
-    prefill = jax.jit(model.decode_step)
+    step, prefill = _programs(model, stats)
 
     # one independent state per slot (batch=1) so refills don't disturb others
     states = [model.init_decode_state(1, max_seq, cache_dtype) for _ in range(slots)]

@@ -8,7 +8,9 @@ real smoke-config LM.
 """
 
 import dataclasses
+import gc
 import json
+import weakref
 from pathlib import Path
 
 import jax
@@ -200,8 +202,8 @@ def test_serve_text_ring_cache_round_trip(row_program):
 def test_serve_text_records_first_tokens_gaps_and_spans(row_program):
     """The ledger's per-token hook: every served uid gets a first-token
     time below its latency and ``len(out) - 1`` token gaps; prefill and
-    decode are measured spans, not a residual; the fresh per-call programs
-    count as compiles."""
+    decode are measured spans, not a residual; a fresh model instance per
+    call is what makes each call compile."""
     rp, _ = row_program
     stats = ServeStats()
     for uid in range(3):  # one request per call: each call's gaps are its own
@@ -250,6 +252,54 @@ def test_serve_text_end_to_end_smoke(row_program):
     # greedy decode is deterministic: a re-serve reproduces every token
     rerun = serve_text(model, params, rp, reqs, slots=2, max_seq=32)
     assert rerun == results
+
+
+def _smoke_lm(name, tok):
+    cfg = dataclasses.replace(get_smoke(name), vocab_size=len(tok.itos))
+    model = LM(cfg, remat=False, dtype=jnp.float32)
+    return model, model.init(jax.random.PRNGKey(0))
+
+
+def test_serve_text_reuses_the_models_programs(row_program):
+    """A model's step and prefill are made on its first serve and found on
+    every later one: a second call compiles nothing and answers the same.
+    The programs belong to their instance: two models of different configs
+    served in turn each answer as when served alone."""
+    rp, tok = row_program
+    reqs = [TextRequest(i, CORPUS[i]["abstract"], max_new=4) for i in range(3)]
+
+    def serve(model, params, stats=None):
+        return serve_text(model, params, rp, reqs, slots=2, max_seq=32, stats=stats)
+
+    model, params = _smoke_lm("stablelm_3b", tok)
+    first, second = ServeStats(), ServeStats()
+    out = serve(model, params, first)
+    assert (first.program_builds, first.program_reuses) == (1, 0)
+    assert first.compiles > 0
+    assert serve(model, params, second) == out
+    assert (second.program_builds, second.program_reuses) == (0, 1)
+    # nothing is compiled or loaded; only eager ops' cached primitives re-trace
+    assert second.compiles == 0 and second.compile_s < first.compile_s / 10
+
+    names = ("stablelm_3b", "recurrentgemma_9b")
+    alone = {n: serve(*_smoke_lm(n, tok)) for n in names}
+    models = {n: _smoke_lm(n, tok) for n in names}
+    for _ in range(2):
+        for n in names:
+            assert serve(*models[n]) == alone[n], n
+
+
+def test_served_model_is_not_kept_alive(row_program):
+    """The programs kept for a model do not keep it alive once its caller
+    drops it."""
+    rp, tok = row_program
+    model, params = _smoke_lm("stablelm_3b", tok)
+    serve_text(model, params, rp, [TextRequest(0, CORPUS[0]["abstract"], max_new=3)],
+               slots=2, max_seq=32)
+    dead = weakref.ref(model)
+    del model
+    gc.collect()
+    assert dead() is None
 
 
 # -- R005: the serve hot path stays free of shard machinery -----------------
