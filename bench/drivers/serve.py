@@ -17,10 +17,28 @@ answered with a whole answer; for a seeded sample of the answered
 requests (the one with the most served tokens among them), the row
 program's token row equals the plain reference of the plan, and the
 served tokens lie close to the reference model's best at each position.
+
+Everything that depends on the model comes from the plain reference
+module the configuration names (``"reference": "<module>"``, the file
+``bench/reference/<module>.py``), and from nowhere else:
+
+* ``PROGRAM_KEYS``: configuration key -> attribute path on the program's
+  ``ArchConfig`` (dotted paths reach nested groups) that must equal it;
+* ``init_params(key, cfg)``: the seeded weights in the program's layout;
+* ``request_flops(cfg, prompt_len, generated)``: the matrix operations
+  of one served request;
+* ``served_gaps(params, prompts, served, cfg, *, control=False)``: the
+  gaps of the served tokens below the reference's best logits (with
+  ``control``, also those of the reference at the next lower precision).
+
+A new model architecture is served by a configuration file and a
+reference module, with no edit here.
 """
 
 from __future__ import annotations
 
+import functools
+import importlib
 import sys
 import time
 from pathlib import Path
@@ -29,25 +47,36 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench import corpus, flops
+from bench import corpus
 from bench.drivers.train import prng_key
-from bench.reference import lm as ref_lm
 from bench.reference import text as ref_text
 
 NONE_READ = 1e30  # a reading where nothing could be compared: fails every limit
 ARRIVALS = 20240613  # the one order of the arrival gaps, the same for every seed
+REFERENCES = Path(__file__).resolve().parents[1] / "reference"
 
-PROGRAM_KEYS = {  # configuration file key -> the program's ArchConfig field
-    "num_hidden_layers": "n_layers",
-    "hidden_size": "d_model",
-    "num_attention_heads": "n_heads",
-    "num_key_value_heads": "n_kv_heads",
-    "intermediate_size": "d_ff",
-    "vocab_size": "vocab_size",
-    "rope_theta": "rope_theta",
-    "use_qkv_bias": "qkv_bias",
-    "tie_word_embeddings": "tie_embeddings",
-}
+
+def reference(cfg: dict):
+    """The plain reference module that the configuration names."""
+    name = cfg.get("reference")
+    if name is None:
+        raise KeyError(
+            f"the serving configuration of {cfg.get('program_config')!r} has no \"reference\" key: its file "
+            f"in bench/configs/ must name its plain reference, \"reference\": \"<module>\" for "
+            f"bench/reference/<module>.py"
+        )
+    module = f"bench.reference.{name}"
+    if str(name).isidentifier():
+        try:
+            return importlib.import_module(module)
+        except ModuleNotFoundError as e:
+            if e.name != module:
+                raise
+    there = sorted(p.stem for p in REFERENCES.glob("*.py") if p.stem != "__init__")
+    raise KeyError(
+        f"the configuration names reference {name!r}, but there is no bench/reference/{name}.py; "
+        f"there are {there}"
+    )
 
 
 def schedule(traffic: dict, seconds: float, seed: int):
@@ -98,15 +127,22 @@ class ServeSession:
         self.workdir = Path(workdir)
         self.annotate = annotate
         self.serve_cfg = cfg["serve"]
+        self.ref = reference(cfg)
+        self.dtype = getattr(jnp, cfg["dtype"])
+        self.cache_dtype = getattr(jnp, cfg["serve"]["cache_dtype"])
 
     def program_config(self):
         from repro.configs import get
 
         arch = get(self.cfg["program_config"])
-        for key, field in PROGRAM_KEYS.items():
-            if getattr(arch, field) != self.cfg[key]:
+        for key, path in self.ref.PROGRAM_KEYS.items():
+            try:
+                value = functools.reduce(getattr, path.split("."), arch)
+            except AttributeError:
+                value = "<absent>"
+            if value != self.cfg[key]:
                 raise ValueError(
-                    f"{self.cfg['program_config']}.{field} = {getattr(arch, field)!r} but the "
+                    f"{self.cfg['program_config']}.{path} = {value!r} but the "
                     f"benchmark runs {key} = {self.cfg[key]!r}"
                 )
         return arch
@@ -132,10 +168,10 @@ class ServeSession:
         self.row_program = RecordingRowProgram(program, self.annotate)
         t1 = time.perf_counter()
 
-        self.model = LM(self.program_config(), remat=False, dtype=jnp.bfloat16)
+        self.model = LM(self.program_config(), remat=False, dtype=self.dtype)
         key = prng_key(self.seed)
         # the key is an argument, so one compiled program serves every seed
-        self.params = jax.block_until_ready(jax.jit(lambda k: ref_lm.init_params(k, self.cfg))(key))
+        self.params = jax.block_until_ready(jax.jit(lambda k: self.ref.init_params(k, self.cfg))(key))
         self.serve_text, self.TextRequest, self.RingCache = serve_text, TextRequest, RingCache
         t2 = time.perf_counter()
         self.requests = schedule(self.traffic, self.seconds, self.seed)
@@ -165,7 +201,7 @@ class ServeSession:
         sc = self.serve_cfg
         return self.serve_text(
             self.model, self.params, row_program, reqs, slots=sc["slots"], max_seq=sc["max_seq"],
-            cache=cache, cache_dtype=jnp.bfloat16, stats=stats,
+            cache=cache, cache_dtype=self.cache_dtype, stats=stats,
         )
 
     # -- window ------------------------------------------------------------
@@ -209,7 +245,7 @@ class ServeSession:
         self.results, self.rejected = results, stats.rejected
         served = [k for k in results if results[k]]
         work = sum(
-            flops.lm_request_flops(self.cfg, self._prompt_len(k), len(results[k])) for k in served
+            self.ref.request_flops(self.cfg, self._prompt_len(k), len(results[k])) for k in served
         )
         lat = np.array([latency[k] for k in served])
         return {
@@ -269,13 +305,12 @@ class ServeSession:
             prompts.append(want)
             served.append(self.results[k])
         out.update(token_rows_wrong=float(rows_wrong), sampled_tokens=float(n_tok))
-        theta = self.cfg["rope_theta"]
         if control:
-            gaps, ctrl = ref_lm.served_gaps(self.params, prompts, served, theta=theta, control=True)
+            gaps, ctrl = self.ref.served_gaps(self.params, prompts, served, self.cfg, control=True)
             out["control_logit_gap"] = float(ctrl.max())
             out["control_logit_gap_mean"] = float(ctrl.mean())
         else:
-            gaps = ref_lm.served_gaps(self.params, prompts, served, theta=theta)
+            gaps = self.ref.served_gaps(self.params, prompts, served, self.cfg)
         out["logit_gap"] = float(gaps.max())
         out["logit_gap_mean"] = float(gaps.mean())
         return out

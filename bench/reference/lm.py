@@ -13,6 +13,11 @@ matmul precision from the served bfloat16 weights.
 ``quant=True`` is the control: every linear layer's weight (per output
 channel) and input (per token) rounded to float8 e4m3, scaled so that the
 largest magnitude of each lands on the format's largest number.
+
+A serving configuration names this module with ``"reference": "lm"``;
+the serve driver takes from it the four names every reference module
+gives: ``PROGRAM_KEYS``, ``init_params``, ``request_flops`` and
+``served_gaps``.
 """
 
 from __future__ import annotations
@@ -23,16 +28,37 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from bench import flops
 
-def init_params(key, cfg: dict, dtype=jnp.bfloat16) -> dict:
+# configuration file key -> the attribute of the program's ArchConfig that
+# must equal it (a dotted path reaches into a nested group)
+PROGRAM_KEYS = {
+    "num_hidden_layers": "n_layers",
+    "hidden_size": "d_model",
+    "num_attention_heads": "n_heads",
+    "num_key_value_heads": "n_kv_heads",
+    "intermediate_size": "d_ff",
+    "vocab_size": "vocab_size",
+    "rope_theta": "rope_theta",
+    "use_qkv_bias": "qkv_bias",
+    "tie_word_embeddings": "tie_embeddings",
+}
+
+BLOCK = 8  # sequences a reference forward pass takes at once
+
+request_flops = flops.lm_request_flops
+
+
+def init_params(key, cfg: dict) -> dict:
     """Seeded weights in the system's parameter layout (layers stacked on a
-    leading axis), made in ``dtype``. Each matrix is normal with std
-    1/sqrt(fan_in); the two projections back into the residual are scaled
-    by 1/sqrt(2 * layers) so the residual stream stays O(1) over depth;
-    the embedding has std 1/sqrt(d_model), so that after the sqrt(d_model)
-    scale a token enters the residual at unit scale."""
+    leading axis), made in the configuration's ``dtype``. Each matrix is
+    normal with std 1/sqrt(fan_in); the two projections back into the
+    residual are scaled by 1/sqrt(2 * layers) so the residual stream stays
+    O(1) over depth; the embedding has std 1/sqrt(d_model), so that after
+    the sqrt(d_model) scale a token enters the residual at unit scale."""
     d, f, v, n = cfg["hidden_size"], cfg["intermediate_size"], cfg["vocab_size"], cfg["num_hidden_layers"]
     nh = cfg["num_attention_heads"]
+    dtype = getattr(jnp, cfg["dtype"])
     hd = d // nh
     ks = iter(jax.random.split(key, 16))
     res = 1.0 / np.sqrt(2 * n)
@@ -139,15 +165,17 @@ def _logits_at(params, tokens, rows, cols, *, quant, theta):
     return h @ w
 
 
-def served_gaps(params, prompts, served, *, theta: float = 10000.0, block: int = 8, control: bool = False):
+def served_gaps(params, prompts, served, cfg: dict, *, control: bool = False):
     """For each served token, how far the reference's logit of it lies
     below the reference's best logit at that position.
 
     ``prompts[i]`` and ``served[i]`` are the token ids of request i. The
     token ``served[i][j]`` was produced at position ``len(prompts[i]) - 1 + j``
-    of the sequence ``prompts[i] + served[i][:-1]``. Returns the gaps as
-    one float64 array; with ``control`` also the gaps of the tokens that
-    the float8 control puts first at the same positions."""
+    of the sequence ``prompts[i] + served[i][:-1]``. The rotary base is the
+    configuration's ``rope_theta``. Returns the gaps as one float64 array;
+    with ``control`` also the gaps of the tokens that the float8 control
+    puts first at the same positions."""
+    theta, block = cfg["rope_theta"], BLOCK
     order = sorted(range(len(prompts)), key=lambda i: len(prompts[i]) + len(served[i]))
     gaps, ctrl_gaps = [], []
     with jax.default_matmul_precision("highest"):

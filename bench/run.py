@@ -4,8 +4,9 @@
 
 The cell (an entry of ``workloads`` in ``BENCHMARK.json``) names a
 configuration (its file holds the sizes as run) and a traffic mix
-(``bench/traffic/<traffic>.json``, which names its driver under
-``bench/drivers/``). Each metric of ``BENCHMARK.json`` is read by
+(``bench/traffic/<traffic>.json``, which names its driver: the run drives
+the session class of ``bench/drivers/<driver>.py``, see
+:func:`session_class`). Each metric of ``BENCHMARK.json`` is read by
 ``bench/metrics/<metric>.py``; each number that decides ``correct`` is
 held to its limit in ``bench/limits/<workload>.json``.
 
@@ -39,11 +40,33 @@ from pathlib import Path  # noqa: E402
 BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
 COMPILE_CACHE = ROOT / ".jax_cache"
+DRIVERS = BENCH / "drivers"
 
 
 def fail(why: str) -> int:
     print(f"bench: {why}", file=sys.stderr)
     return 1
+
+
+def driver_path(driver: str) -> Path:
+    """``bench/drivers/<driver>.py``; an unknown driver is an error that
+    lists the drivers there are."""
+    path = DRIVERS / f"{driver}.py"
+    if not (str(driver).isidentifier() and path.is_file()):
+        there = sorted(p.stem for p in DRIVERS.glob("*.py") if p.stem != "__init__")
+        raise KeyError(f"the traffic names driver {driver!r}, but there is no bench/drivers/{driver}.py; "
+                       f"there are {there}")
+    return path
+
+
+def session_class(driver: str):
+    """The session class of ``bench/drivers/<driver>.py``: the driver's name
+    in CamelCase and ``Session`` (``serve`` -> ``ServeSession``). It is
+    looked up on the module when the run starts, so a subclass put in its
+    place (``bench/program_trace.py`` does so) is what runs."""
+    driver_path(driver)
+    module = importlib.import_module(f"bench.drivers.{driver}")
+    return getattr(module, driver.title().replace("_", "") + "Session")
 
 
 def load_cell(name: str) -> dict:
@@ -53,10 +76,13 @@ def load_cell(name: str) -> dict:
         raise KeyError(f"no workload {name!r} in BENCHMARK.json; there are {sorted(cells)}")
     cell = cells[name]
     configs = {c["name"]: c for c in spec["configs"]}
+    traffic = json.loads((BENCH / "traffic" / f"{cell['traffic']}.json").read_text())
+    driver_path(traffic["driver"])
     return {
         "cell": cell,
+        "config_file": configs[cell["config"]]["file"],
         "config": json.loads((ROOT / configs[cell["config"]]["file"]).read_text()),
-        "traffic": json.loads((BENCH / "traffic" / f"{cell['traffic']}.json").read_text()),
+        "traffic": traffic,
         "limits": json.loads((BENCH / "limits" / f"{name}.json").read_text()),
         "spec": spec,
     }
@@ -159,18 +185,19 @@ def main(argv=None) -> int:
     device = {"platform": devices[0].platform, "kind": devices[0].device_kind, "count": len(devices)}
 
     from bench import flops
-    from bench.drivers import serve, train
 
     events = CompileEvents()
     jax.monitoring.register_event_listener(events.event)
     jax.monitoring.register_event_duration_secs_listener(events.event)
     cfg, traffic = cell["config"], cell["traffic"]
-    sessions = {"train": train.TrainSession, "serve": serve.ServeSession}
     with tempfile.TemporaryDirectory(prefix="bench_") as tmp:
         work = Path(tmp)
-        session = sessions[traffic["driver"]](
-            cfg, traffic, args.seed, args.seconds, work, annotate=jax.profiler.TraceAnnotation
-        )
+        try:
+            session = session_class(traffic["driver"])(
+                cfg, traffic, args.seed, args.seconds, work, annotate=jax.profiler.TraceAnnotation
+            )
+        except KeyError as e:  # a key the driver needs is missing from the configuration
+            return fail(f"cannot set up the cell from {cell['config_file']}: {e}")
         session.setup()
         setup_s = time.perf_counter() - T_START
         before = events.snapshot()

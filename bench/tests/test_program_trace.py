@@ -119,5 +119,5 @@ def test_serve_cell_program_view(stub, capsys, monkeypatch):  # noqa: F811
     assert prog["served"] == result["attempted"] == 3
     assert 0 < prog["serve.ttft_p50_ms"] < prog["serve_p50_ms"]
     assert prog["serve.token_gap_p95_ms"] >= prog["serve.token_gap_p50_ms"] > 0
-    assert prog["compiles"] > 0 and prog["serve.compile_share"] > 0
+    assert prog["compiles"] == 0  # set-up's warm-up made every program the window runs
     assert lines["trace"]["span_share"]["serve.decode_step"] > 0
