@@ -27,6 +27,46 @@ class MoEConfig:
     capacity_factor: float = 1.25
     router_aux_weight: float = 0.01
     expert_impl: str = "ragged"  # "ragged" | "batched" (see models.moe)
+    norm_topk_prob: bool = True  # renormalise the top-k weights to sum to 1
+    routed_scaling_factor: float = 1.0  # multiplies the routed experts' weights
+    # the share of the routed experts this chip holds: experts first_held ..
+    # first_held + held - 1 (held 0: all of them). The router still scores
+    # all n_experts; the others' part of the output lies on other chips.
+    held: int = 0
+    first_held: int = 0
+
+    @property
+    def n_held(self) -> int:
+        return self.held or self.n_experts
+
+
+@dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention (DeepSeek-V2), without query compression:
+    keys and values come from one ``kv_lora_rank``-wide latent per token;
+    each head's query and key are ``qk_nope_head_dim`` unrotated dims plus
+    ``qk_rope_head_dim`` rotary dims, the rotary key shared by all heads."""
+
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+
+@dataclass(frozen=True)
+class YarnScaling:
+    """YaRN rotary scaling (arXiv:2309.00071, as DeepSeek-V2 configures it)."""
+
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclass(frozen=True)
@@ -44,6 +84,9 @@ class ArchConfig:
     qkv_bias: bool = False
     rope: str = "rope"  # rope | mrope | none
     rope_theta: float = 10000.0
+    rope_scaling: YarnScaling | None = None  # YaRN; read by latent attention (mla) only
+    mla: MLAConfig | None = None  # set: latent attention in place of MHA
+    embed_scale: bool = True  # multiply the token embedding by sqrt(d_model)
     causal: bool = True  # False -> encoder-only (hubert)
     window: int = 0  # >0 -> sliding-window attention
     block_pattern: tuple[str, ...] = ("attn",)  # unit scanned over depth
@@ -84,6 +127,14 @@ class ArchConfig:
 
     # -- analytic parameter counts (used by rooflines: 6·N·D) --------------
     def _attn_params(self) -> int:
+        if self.mla is not None:
+            a, h = self.mla, self.n_heads
+            return (
+                self.d_model * h * a.qk_head_dim  # q
+                + self.d_model * (a.kv_lora_rank + a.qk_rope_head_dim)  # latent + rotary key
+                + a.kv_lora_rank * h * (a.qk_nope_head_dim + a.v_head_dim)  # latent -> k, v
+                + h * a.v_head_dim * self.d_model  # out
+            )
         d, hd = self.d_model, self.resolved_head_dim
         n_q, n_kv = self.n_heads * hd, self.n_kv_heads * hd
         p = d * n_q + 2 * d * n_kv + n_q * d
@@ -99,7 +150,7 @@ class ArchConfig:
         if kind == "attn":
             if self.moe is not None:
                 m = self.moe
-                experts = (m.n_experts + m.n_shared) * self._mlp_params_w(m.d_expert)
+                experts = (m.n_held + m.n_shared) * self._mlp_params_w(m.d_expert)
                 return self._attn_params() + experts + d * m.n_experts
             return self._attn_params() + self._mlp_params(self.d_ff)
         if kind == "rglru":
@@ -136,14 +187,17 @@ class ArchConfig:
         return total
 
     def active_param_count(self) -> int:
-        """Activated parameters per token (MoE: top_k + shared only)."""
+        """Activated parameters per token (MoE: top_k + shared only; of a
+        held share, the top_k * n_held / n_experts held experts a token
+        reaches on average)."""
         if self.moe is None:
             return self.param_count()
         m = self.moe
+        reached = m.top_k * m.n_held / m.n_experts
         inactive = (self.n_layers - m.first_k_dense) * (
-            m.n_experts - m.top_k
+            m.n_held - reached
         ) * self._mlp_params(m.d_expert)
-        return self.param_count() - inactive
+        return self.param_count() - round(inactive)
 
 
 @dataclass(frozen=True)
@@ -172,6 +226,7 @@ ARCH_IDS = [
     "recurrentgemma_9b",
     "xlstm_1_3b",
     "qwen2_vl_72b",
+    "deepseek_v2_lite",
 ]
 
 

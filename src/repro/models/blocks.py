@@ -10,6 +10,7 @@ Conventions
 
 from __future__ import annotations
 
+import math
 
 import jax
 import jax.numpy as jnp
@@ -112,13 +113,18 @@ def embed_axes(cfg) -> dict:
 
 def embed_tokens(p: dict, tokens: jax.Array, cfg) -> jax.Array:
     x = jnp.take(p["embedding"], tokens, axis=0)
+    if not cfg.embed_scale:
+        return x
     return x * jnp.asarray(np.sqrt(cfg.d_model), x.dtype)
 
 
 def lm_logits(p: dict, x: jax.Array, cfg) -> jax.Array:
     if cfg.tie_embeddings:
+        logits = x @ p["embedding"].T
+        if not cfg.embed_scale:
+            return logits
         # un-scale: embed_tokens multiplied by sqrt(d); keep logits O(1)
-        return (x @ p["embedding"].T) / jnp.asarray(np.sqrt(cfg.d_model), x.dtype)
+        return logits / jnp.asarray(np.sqrt(cfg.d_model), x.dtype)
     return x @ p["lm_head"]
 
 
@@ -127,16 +133,51 @@ def lm_logits(p: dict, x: jax.Array, cfg) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 
-def rope_frequencies(head_dim: int, theta: float = 10000.0) -> np.ndarray:
-    return 1.0 / (theta ** (np.arange(0, head_dim, 2, dtype=np.float64) / head_dim))
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature term: 0.1 * mscale * ln(factor) + 1."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float = 10000.0) -> jax.Array:
-    """x: (..., seq, heads, head_dim); positions: broadcastable to (..., seq)."""
+def yarn_correction_range(scaling, head_dim: int, theta: float) -> tuple[int, int]:
+    """The frequency pairs (low, high) between which YaRN ramps from the
+    unscaled frequencies (below low) to the interpolated ones (above high):
+    pair i turns ``beta`` times in ``original_max_position`` positions at
+    i = head_dim * ln(L / (2 pi beta)) / (2 ln theta)."""
+
+    def pair(beta: float) -> float:
+        return head_dim * math.log(scaling.original_max_position / (beta * 2 * math.pi)) / (
+            2 * math.log(theta)
+        )
+
+    low = math.floor(pair(scaling.beta_fast))
+    high = math.ceil(pair(scaling.beta_slow))
+    return max(low, 0), min(high, head_dim - 1)
+
+
+def rope_frequencies(head_dim: int, theta: float = 10000.0, scaling=None) -> np.ndarray:
+    freqs = 1.0 / (theta ** (np.arange(0, head_dim, 2, dtype=np.float64) / head_dim))
+    if scaling is None:
+        return freqs
+    low, high = yarn_correction_range(scaling, head_dim, theta)
+    ramp = np.clip((np.arange(head_dim // 2) - low) / max(high - low, 0.001), 0.0, 1.0)
+    return freqs / scaling.factor * ramp + freqs * (1.0 - ramp)
+
+
+def apply_rope(
+    x: jax.Array, positions: jax.Array, theta: float = 10000.0, scaling=None
+) -> jax.Array:
+    """x: (..., seq, heads, head_dim); positions: broadcastable to (..., seq).
+    With ``scaling`` (a YarnScaling), YaRN's frequencies, and cos and sin
+    times mscale(factor, mscale) / mscale(factor, mscale_all_dim)."""
     head_dim = x.shape[-1]
-    freqs = jnp.asarray(rope_frequencies(head_dim, theta), jnp.float32)
+    freqs = jnp.asarray(rope_frequencies(head_dim, theta, scaling), jnp.float32)
     angles = positions[..., :, None, None].astype(jnp.float32) * freqs  # (..., s, 1, hd/2)
     sin, cos = jnp.sin(angles), jnp.cos(angles)
+    if scaling is not None:
+        m = yarn_mscale(scaling.factor, scaling.mscale) / yarn_mscale(
+            scaling.factor, scaling.mscale_all_dim
+        )
+        sin, cos = sin * m, cos * m
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)

@@ -77,7 +77,8 @@ class MeshContext(NamedTuple):
 def _init_block(key, cfg, kind: str, moe_layer: bool, dtype) -> dict:
     k1, k2, k3, k4 = jax.random.split(key, 4)
     if kind == "attn":
-        p = {"norm1": init_norm(cfg, dtype), "attn": A.init_attention(k1, cfg, dtype),
+        init_attn = A.init_mla if cfg.mla is not None else A.init_attention
+        p = {"norm1": init_norm(cfg, dtype), "attn": init_attn(k1, cfg, dtype),
              "norm2": init_norm(cfg, dtype)}
         if moe_layer:
             p["moe"] = MOE.init_moe(k2, cfg, dtype)
@@ -99,7 +100,8 @@ def _init_block(key, cfg, kind: str, moe_layer: bool, dtype) -> dict:
 
 def _block_axes(cfg, kind: str, moe_layer: bool) -> dict:
     if kind == "attn":
-        p = {"norm1": norm_axes(cfg), "attn": A.attention_axes(cfg), "norm2": norm_axes(cfg)}
+        attn_axes = A.mla_axes if cfg.mla is not None else A.attention_axes
+        p = {"norm1": norm_axes(cfg), "attn": attn_axes(cfg), "norm2": norm_axes(cfg)}
         if moe_layer:
             p["moe"] = MOE.moe_axes(cfg)
         else:
@@ -130,7 +132,8 @@ def _apply_block(
     """Returns (x, new_cache, aux)."""
     aux = jnp.zeros((), jnp.float32)
     if kind == "attn":
-        h, new_cache = A.attend(
+        attend = A.attend_mla if cfg.mla is not None else A.attend
+        h, new_cache = attend(
             p["attn"], apply_norm(p["norm1"], x, cfg.norm), cfg,
             positions=positions, cache=cache, cache_pos=cache_pos,
         )
@@ -162,6 +165,8 @@ def _apply_block(
 
 def _init_block_cache(cfg, kind: str, batch: int, max_seq: int, dtype):
     if kind == "attn":
+        if cfg.mla is not None:
+            return A.init_mla_cache(batch, max_seq, cfg, dtype)
         return A.init_kv_cache(batch, max_seq, cfg, dtype)
     if kind == "rglru":
         return RG.init_rglru_state(batch, cfg, dtype)
@@ -335,9 +340,12 @@ class LM:
 
     def decode_state_axes(self) -> dict:
         """Logical-axis annotations matching init_decode_state structure."""
+        cfg = self.cfg
 
         def block_axes(kind: str):
             if kind == "attn":
+                if cfg.mla is not None:
+                    return A.MLACache(("batch", "seq", None), ("batch", "seq", None))
                 kv = ("batch", "seq", "kv_heads", "head_dim")
                 return A.KVCache(kv, kv)
             if kind == "rglru":

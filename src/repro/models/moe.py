@@ -16,6 +16,14 @@ Routed experts + optional shared experts. Two execution paths:
 The routed output is replicated over the model axis by construction (every
 model rank sends identical buffers), so ``check_vma=False`` is used and the
 combine result carries data-parallel sharding only.
+
+A layer may hold a share of the routed experts (``MoEConfig.held`` from
+``first_held``), as one chip of an expert-parallel deployment does: the
+router scores all ``n_experts`` with the published top-k, and the local
+path computes exactly the part of the output that the held experts give
+(no capacity, nothing dropped) plus the shared experts; the absent
+experts' part is left out. Both paths compute their experts' part with
+:func:`_expert_ffn`. Spans: ``moe.route``, ``moe.experts``, ``moe.shared``.
 """
 
 from __future__ import annotations
@@ -40,9 +48,9 @@ def init_moe(key, cfg, dtype) -> dict:
     s = cfg.init_scale / np.sqrt(d)
     p = {
         "router": truncated_normal(kr, (d, m.n_experts), jnp.float32, s),
-        "w_gate": truncated_normal(kg, (m.n_experts, d, f), dtype, s),
-        "w_up": truncated_normal(ku, (m.n_experts, d, f), dtype, s),
-        "w_down": truncated_normal(kd, (m.n_experts, f, d), dtype, cfg.init_scale / np.sqrt(f)),
+        "w_gate": truncated_normal(kg, (m.n_held, d, f), dtype, s),
+        "w_up": truncated_normal(ku, (m.n_held, d, f), dtype, s),
+        "w_down": truncated_normal(kd, (m.n_held, f, d), dtype, cfg.init_scale / np.sqrt(f)),
     }
     if m.n_shared:
         ks1, ks2, ks3 = jax.random.split(ks, 3)
@@ -73,11 +81,16 @@ def moe_axes(cfg) -> dict:
 
 
 def _route(xf: jax.Array, router: jax.Array, m) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """Top-k routing. Returns (expert_ids (N,k), probs (N,k), aux_loss)."""
+    """Top-k routing over all ``m.n_experts``: a softmax, then greedy top-k,
+    renormalised to sum to 1 where ``m.norm_topk_prob`` (DeepSeekMoE) and
+    times ``m.routed_scaling_factor``. Returns (expert_ids (N,k), probs
+    (N,k), aux_loss)."""
     logits = (xf.astype(jnp.float32) @ router).astype(jnp.float32)  # (N, E)
     probs_full = jax.nn.softmax(logits, axis=-1)
     probs, ids = jax.lax.top_k(probs_full, m.top_k)
-    probs = probs / jnp.maximum(probs.sum(-1, keepdims=True), 1e-9)  # renorm (DeepSeek)
+    if m.norm_topk_prob:
+        probs = probs / jnp.maximum(probs.sum(-1, keepdims=True), 1e-9)
+    probs = probs * m.routed_scaling_factor
     # Switch/GShard load-balance aux: E * sum_e f_e * P_e
     pe = probs_full.mean(axis=0)
     fe = jnp.zeros((m.n_experts,), jnp.float32).at[ids.reshape(-1)].add(1.0)
@@ -142,20 +155,28 @@ def _expert_ffn(tokens: jax.Array, eids: jax.Array, p: dict, n_experts: int,
 
 
 def moe_local(p: dict, x: jax.Array, cfg) -> tuple[jax.Array, jax.Array]:
-    """Single-shard routed-experts forward. x: (b, s, d)."""
+    """Single-shard routed-experts forward of the held experts. x: (b, s, d).
+
+    Routes over all ``n_experts``; an assignment to an expert this layer
+    does not hold becomes a zero token under the trash id ``n_held`` and
+    weighs nothing in the combine."""
     m = cfg.moe
     b, s, d = x.shape
     xf = x.reshape(-1, d)
-    ids, probs, aux = _route(xf, p["router"], m)
-    n, k = ids.shape
-    tok_idx = jnp.repeat(jnp.arange(n), k)
-    flat_ids = ids.reshape(-1)
-    out_flat = _expert_ffn(
-        xf[tok_idx], flat_ids, p, m.n_experts,
-        impl=getattr(m, "expert_impl", "ragged"),
-        capacity_factor=m.capacity_factor + 0.25,
-    )
-    y = jnp.zeros_like(xf).at[tok_idx].add(out_flat * probs.reshape(-1)[:, None])
+    with jax.named_scope("moe.route"):
+        ids, probs, aux = _route(xf, p["router"], m)
+    with jax.named_scope("moe.experts"):
+        n, k = ids.shape
+        tok_idx = jnp.repeat(jnp.arange(n), k)
+        local = ids.reshape(-1) - m.first_held
+        held = (local >= 0) & (local < m.n_held)
+        out_flat = _expert_ffn(
+            jnp.where(held[:, None], xf[tok_idx], 0), jnp.where(held, local, m.n_held), p, m.n_held,
+            impl=getattr(m, "expert_impl", "ragged"),
+            capacity_factor=m.capacity_factor + 0.25,
+        )
+        w = jnp.where(held, probs.reshape(-1), 0)
+        y = jnp.zeros_like(xf).at[tok_idx].add(out_flat * w[:, None])
     return y.reshape(b, s, d), aux
 
 
@@ -174,7 +195,8 @@ def _moe_ep_body(p: dict, x: jax.Array, cfg, model_axis: str, data_axes: tuple[s
     xf = x.reshape(-1, d)
     n = xf.shape[0]
 
-    ids, probs, aux = _route(xf, p["router"], m)
+    with jax.named_scope("moe.route"):
+        ids, probs, aux = _route(xf, p["router"], m)
     flat_ids = ids.reshape(-1)  # (n*k,)
     tok_idx = jnp.repeat(jnp.arange(n), m.top_k)
     owner = flat_ids // e_loc
@@ -203,11 +225,12 @@ def _moe_ep_body(p: dict, x: jax.Array, cfg, model_axis: str, data_axes: tuple[s
     recv_eid = jax.lax.all_to_all(send_eid, model_axis, split_axis=0, concat_axis=0, tiled=False)
 
     # local expert compute (dropped slots are zero tokens -> zero outputs)
-    out = _expert_ffn(
-        recv_tok.reshape(-1, d), recv_eid.reshape(-1), p, e_loc,
-        impl=getattr(m, "expert_impl", "ragged"),
-        capacity_factor=m.capacity_factor + 0.25,
-    )
+    with jax.named_scope("moe.experts"):
+        out = _expert_ffn(
+            recv_tok.reshape(-1, d), recv_eid.reshape(-1), p, e_loc,
+            impl=getattr(m, "expert_impl", "ragged"),
+            capacity_factor=m.capacity_factor + 0.25,
+        )
     out = out.reshape(n_shards, cap, d)
 
     # reverse exchange and weighted combine
@@ -255,6 +278,7 @@ def apply_moe(
         mesh is not None
         and model_axis
         and mesh.shape[model_axis] > 1
+        and m.n_held == m.n_experts
         and m.n_experts % mesh.shape[model_axis] == 0
     )
     if use_ep:
@@ -262,7 +286,8 @@ def apply_moe(
     else:
         y, aux = moe_local(p, x, cfg)
     if m.n_shared:
-        sp = p["shared"]
-        h = jax.nn.silu((x @ sp["gate"]).astype(jnp.float32)).astype(x.dtype) * (x @ sp["up"])
-        y = y + h @ sp["down"]
+        with jax.named_scope("moe.shared"):
+            sp = p["shared"]
+            h = jax.nn.silu((x @ sp["gate"]).astype(jnp.float32)).astype(x.dtype) * (x @ sp["up"])
+            y = y + h @ sp["down"]
     return y, aux

@@ -173,7 +173,9 @@ class ServeStats:
     compiled or loaded from the compilation cache while a call ran, and
     their time. ``program_builds`` and ``program_reuses``: per call,
     whether the model's step and prefill programs had to be made or were
-    found on the model. Per request (by uid): ``latency_s`` from the admission
+    found on the model. ``state_bytes``: the bytes of one slot's decode
+    state (its cache), counted from the state's shapes when the slots'
+    states are made. Per request (by uid): ``latency_s`` from the admission
     offer to the final token, ``first_token_s`` from the offer to the
     first token; ``token_gaps_s`` holds, for every later token, its gap
     since the same request's previous token."""
@@ -191,6 +193,7 @@ class ServeStats:
     compile_s: float = 0.0
     program_builds: int = 0
     program_reuses: int = 0
+    state_bytes: int = 0
     latency_s: dict[int, float] = field(default_factory=dict)
     first_token_s: dict[int, float] = field(default_factory=dict)
     token_gaps_s: list[float] = field(default_factory=list)
@@ -279,6 +282,8 @@ def _continuous_decode(
 
     # one independent state per slot (batch=1) so refills don't disturb others
     states = [model.init_decode_state(1, max_seq, cache_dtype) for _ in range(slots)]
+    if stats is not None:
+        stats.state_bytes = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(states[0]))
     active: list[dict | None] = [None] * slots
     last_tok = [None] * slots
 

@@ -1,0 +1,172 @@
+"""Latent attention, held expert shares and YaRN (DeepSeek-V2-Lite), on the
+CPU at a small size with seeded float32 weights, against the plain
+reference ``bench/reference/mla_moe.py`` and against each other."""
+
+import dataclasses
+import json
+import sys
+from pathlib import Path
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+ROOT = Path(__file__).resolve().parents[1]
+if str(ROOT) not in sys.path:
+    sys.path.insert(0, str(ROOT))
+
+from bench.reference import mla_moe as ref  # noqa: E402
+from bench.tests import mla_smoke  # noqa: E402
+from repro.configs import get, get_smoke  # noqa: E402
+from repro.models import attention as A  # noqa: E402
+from repro.models import moe as MOE  # noqa: E402
+from repro.models.blocks import rope_frequencies, yarn_correction_range  # noqa: E402
+from repro.models.lm import LM  # noqa: E402
+
+KEY = jax.random.PRNGKey(0)
+
+# float32 on both sides, the same equations in another order (absorbed
+# against expanded attention, a cache against none): the gaps are float32
+# round-off, ~1e-6 on logits of magnitude ~3; the float8 control lies ~0.2 off.
+LOGIT_ATOL = 2e-5
+
+
+def _model_and_params(**moe):
+    cfg = mla_smoke.cfg(dtype="float32")
+    params = ref.init_params(KEY, cfg)
+    return LM(mla_smoke.arch(**moe), remat=False, dtype=jnp.float32), params, cfg
+
+
+def test_prefill_then_latent_decode_matches_the_reference_forward():
+    model, params, cfg = _model_and_params()
+    b, prompt, total = 2, 12, 24
+    toks = np.asarray(jax.random.randint(jax.random.fold_in(KEY, 1), (b, total), 4, cfg["vocab_size"]))
+    state = model.init_decode_state(b, 32, cache_dtype=jnp.float32)
+    assert isinstance(state["head"][0], A.MLACache)
+    step = jax.jit(model.decode_step)
+    logits, state = step(params, jnp.asarray(toks[:, :prompt]), state, jnp.int32(0))
+    got = [logits[:, 0]]
+    for t in range(prompt, total):
+        logits, state = step(params, jnp.asarray(toks[:, t : t + 1]), state, jnp.int32(t))
+        got.append(logits[:, 0])
+    got = np.stack(got, 1)
+    rows = np.repeat(np.arange(b), total - prompt + 1).astype(np.int32)
+    cols = np.tile(np.arange(prompt - 1, total), b).astype(np.int32)
+    with jax.default_matmul_precision("highest"):
+        want = ref._logits_at(params, jnp.asarray(toks), rows, cols, m=ref.model(cfg), quant=False)
+        low = ref._logits_at(params, jnp.asarray(toks), rows, cols, m=ref.model(cfg), quant=True)
+    want, low = np.asarray(want).reshape(got.shape), np.asarray(low).reshape(got.shape)
+    np.testing.assert_allclose(got, want, rtol=0, atol=LOGIT_ATOL)
+    assert np.abs(low - want).max() > 100 * LOGIT_ATOL  # the tolerance would catch float8
+
+
+def test_held_shares_sum_to_the_uncut_layer():
+    """8 experts in 4 shares of 2: each share routes over all 8 and computes
+    its own experts' part; the parts, with the shared experts once, add up
+    to the uncut reference layer."""
+    cfg = mla_smoke.cfg(dtype="float32", n_routed_experts=8)
+    uncut = ref.init_params(KEY, cfg)["units"][0]["moe"]
+    p = jax.tree.map(lambda a: a[1], uncut)  # MoE layer 2 of the stack
+    x = jax.random.normal(jax.random.fold_in(KEY, 2), (2, 8, cfg["hidden_size"]))
+    arch = mla_smoke.arch()
+    parts = []
+    for first in range(0, 8, 2):
+        share_cfg = dataclasses.replace(arch, moe=dataclasses.replace(arch.moe, held=2, first_held=first))
+        share = dict(p, **{k: p[k][first : first + 2] for k in ("w_gate", "w_up", "w_down")})
+        routed, _ = MOE.moe_local(share, x, share_cfg)
+        layer, _ = MOE.apply_moe(share, x, share_cfg)  # what the chip holding this share adds
+        parts.append((routed, layer))
+    shared = parts[0][1] - parts[0][0]  # computed alike on every chip: counted once
+    total = sum(routed for routed, _ in parts) + shared
+    with jax.default_matmul_precision("highest"):
+        want = ref._experts(x, p, ref.model(cfg), False)
+    assert float(jnp.abs(shared).max()) > 0
+    np.testing.assert_allclose(np.asarray(total), np.asarray(want), rtol=0, atol=1e-5)
+    uncut_cfg = dataclasses.replace(arch, moe=dataclasses.replace(arch.moe, held=8, first_held=0))
+    whole, _ = MOE.apply_moe(p, x, uncut_cfg)
+    np.testing.assert_allclose(np.asarray(whole), np.asarray(want), rtol=0, atol=1e-5)
+
+
+def test_top_k_renormalisation_follows_the_config():
+    arch = get_smoke("deepseek_moe_16b")
+    p = MOE.init_moe(KEY, arch, jnp.float32)
+    x = jax.random.normal(jax.random.fold_in(KEY, 3), (2, 8, arch.d_model))
+    xf = x.reshape(-1, arch.d_model)
+    full = jax.nn.softmax(xf @ p["router"], axis=-1)
+    top, _ = jax.lax.top_k(full, arch.moe.top_k)
+    _, probs, _ = MOE._route(xf, p["router"], arch.moe)
+    np.testing.assert_allclose(np.asarray(probs), np.asarray(top / top.sum(-1, keepdims=True)), rtol=1e-6)
+    raw = dataclasses.replace(arch.moe, norm_topk_prob=False)
+    _, unnormalised, _ = MOE._route(xf, p["router"], raw)
+    np.testing.assert_allclose(np.asarray(unnormalised), np.asarray(top), rtol=1e-6)
+    assert float(unnormalised.sum(-1).max()) < 1.0
+    _, scaled, _ = MOE._route(xf, p["router"], dataclasses.replace(raw, routed_scaling_factor=2.5))
+    np.testing.assert_allclose(np.asarray(scaled), 2.5 * np.asarray(top), rtol=1e-6)
+    # deepseek_moe_16b as before: every assignment held, weights renormalised
+    y, _ = MOE.moe_local(p, x, arch)
+    ids = jax.lax.top_k(full, arch.moe.top_k)[1]
+    w = top / top.sum(-1, keepdims=True)
+    expect = np.zeros(xf.shape, np.float32)
+    for t in range(xf.shape[0]):
+        for j in range(arch.moe.top_k):
+            e = int(ids[t, j])
+            h = jax.nn.silu(xf[t] @ p["w_gate"][e]) * (xf[t] @ p["w_up"][e])
+            expect[t] += float(w[t, j]) * np.asarray(h @ p["w_down"][e])
+    np.testing.assert_allclose(np.asarray(y.reshape(xf.shape)), expect, rtol=2e-4, atol=2e-4)
+
+
+def _published_rope_scaling() -> dict:
+    return json.loads((ROOT / "bench" / "configs" / "deepseek-v2-lite.json").read_text())["rope_scaling"]
+
+
+def test_yarn_at_the_published_values():
+    arch = get("deepseek_v2_lite")
+    assert yarn_correction_range(arch.rope_scaling, 64, arch.rope_theta) == (10, 23)
+    assert A.mla_softmax_scale(arch) == pytest.approx(0.114721, abs=1e-6)
+    cfg = mla_smoke.cfg(rope_scaling=_published_rope_scaling(), qk_rope_head_dim=64, qk_nope_head_dim=128)
+    inv_freq, factor, scale, edges = ref.yarn(cfg)
+    assert edges == (10, 23) and factor == 1.0
+    assert scale == pytest.approx(A.mla_softmax_scale(arch), rel=1e-12)
+    np.testing.assert_allclose(rope_frequencies(64, arch.rope_theta, arch.rope_scaling), inv_freq, rtol=1e-12)
+    plain = rope_frequencies(64, arch.rope_theta)
+    np.testing.assert_allclose(inv_freq[:10], plain[:10])  # below low: unscaled
+    np.testing.assert_allclose(inv_freq[24:], plain[24:] / 40)  # above high: interpolated
+
+
+@pytest.mark.parametrize("block, scope", [(5, "mla.prefill"), (1, "mla.decode")])
+def test_named_scopes_in_the_lowered_programs(block, scope):
+    model, params, _ = _model_and_params()
+    state = model.init_decode_state(1, 16, cache_dtype=jnp.float32)
+    toks = jnp.ones((1, block), jnp.int32)
+    text = jax.jit(model.decode_step).lower(params, toks, state, jnp.int32(0)).as_text(debug_info=True)
+    for name in (scope, "moe.route", "moe.experts", "moe.shared"):
+        assert name in text, name
+    other = "mla.decode" if scope == "mla.prefill" else "mla.prefill"
+    assert other not in text
+
+
+def test_state_bytes_counts_one_latent_slot():
+    from repro.runtime.serve_loop import ServeStats, _continuous_decode
+
+    arch = get("deepseek_v2_lite_ep8")
+    stats = ServeStats()
+    _continuous_decode(LM(arch, dtype=jnp.bfloat16), None, lambda: None, lambda *a: None,
+                       slots=4, max_seq=256, cache_dtype=jnp.bfloat16, stats=stats)
+    assert stats.state_bytes == 27 * 256 * (512 + 64) * 2
+
+
+@pytest.mark.parametrize("sq, skv", [(7, 7), (3, 40)])
+def test_sdpa_takes_the_output_width_from_v(sq, skv):
+    kq, kk, kv = jax.random.split(jax.random.fold_in(KEY, 4), 3)
+    q = jax.random.normal(kq, (2, sq, 4, 192))
+    k = jax.random.normal(kk, (2, skv, 4, 192))
+    v = jax.random.normal(kv, (2, skv, 4, 128))
+    off = skv - sq
+    scores = jnp.einsum("bshk,bthk->bhst", q, k) * 0.1
+    mask = jnp.arange(skv)[None, :] <= jnp.arange(sq)[:, None] + off
+    naive = jnp.einsum("bhst,bthk->bshk", jax.nn.softmax(jnp.where(mask, scores, -jnp.inf), -1), v)
+    kw = dict(causal=True, q_offset=off, scale=0.1)
+    for out in (A.sdpa(q, k, v, **kw), A.chunked_sdpa(q, k, v, q_chunk=2, kv_chunk=16, **kw)):
+        assert out.shape == (2, sq, 4, 128)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(naive), rtol=1e-5, atol=1e-5)

@@ -87,6 +87,7 @@ _EXPECTED_B = {
     "recurrentgemma_9b": (8.5, 10.5),
     "xlstm_1_3b": (1.1, 1.7),
     "qwen2_vl_72b": (68.0, 76.0),
+    "deepseek_v2_lite": (15.0, 16.5),
 }
 
 
@@ -103,9 +104,14 @@ def test_kimi_active_params():
     assert 28.0 <= active <= 40.0  # a32b
 
 
+def test_deepseek_v2_lite_active_params():
+    active = get("deepseek_v2_lite").active_param_count() / 1e9
+    assert 2.2 <= active <= 2.8  # published 15.7B-A2.4B; embedding and head counted here
+
+
 def test_cell_grid():
     cells = all_cells()
-    assert len(cells) == 31
+    assert len(cells) == 34
     assert ("hubert_xlarge", "decode_32k") not in cells
     assert ("hubert_xlarge", "long_500k") not in cells
     assert ("recurrentgemma_9b", "long_500k") in cells
