@@ -128,8 +128,9 @@ def _apply_block(
     positions: jax.Array,
     cache=None,
     cache_pos=0,
+    layer=None,
 ):
-    """Returns (x, new_cache, aux)."""
+    """Returns (x, new_cache, aux). ``layer``: see :func:`moe.apply_moe`."""
     aux = jnp.zeros((), jnp.float32)
     if kind == "attn":
         attend = A.attend_mla if cfg.mla is not None else A.attend
@@ -142,7 +143,7 @@ def _apply_block(
         if moe_layer:
             ff, aux = MOE.apply_moe(
                 p["moe"], h2, cfg, mesh=mctx.mesh,
-                data_axes=mctx.data_axes, model_axis=mctx.model_axis,
+                data_axes=mctx.data_axes, model_axis=mctx.model_axis, layer=layer,
             )
         else:
             ff = apply_mlp(p["mlp"], h2, cfg)
@@ -161,6 +162,14 @@ def _apply_block(
                                      state=cache if cache is not None else None)
         return x + h, new_state, aux
     raise ValueError(kind)
+
+
+def _hold_out_experts(p: dict, moe_layer: bool) -> tuple[dict, dict]:
+    """A stacked block's params without its routed experts, and those."""
+    if not moe_layer:
+        return p, {}
+    rest = {k: v for k, v in p["moe"].items() if k not in MOE.EXPERT_KEYS}
+    return dict(p, moe=rest), {k: p["moe"][k] for k in MOE.EXPERT_KEYS}
 
 
 def _init_block_cache(cfg, kind: str, batch: int, max_seq: int, dtype):
@@ -397,18 +406,27 @@ class LM:
             new_state["head"].append(nc)
 
         if self.n_units:
+            # The routed experts' stacks stay whole, indexed by layer inside
+            # the MoE, so a decode token reads only the experts it reaches
+            # (moe.moe_local); every other weight is scanned.
+            units, experts = zip(*(_hold_out_experts(p, moe)
+                                   for p, moe in zip(params["units"], self.unit_moe)))
+
             def unit_fn(carry, scanned):
                 x = carry
-                unit_p, unit_c = scanned
+                layer, unit_p, unit_c = scanned
                 ncs = []
-                for p, kind, moe, c in zip(unit_p, self.unit_pattern, self.unit_moe, unit_c):
-                    x, nc, _ = _apply_block(p, x, cfg, kind, moe, mctx,
-                                            positions=positions, cache=c, cache_pos=pos)
+                for p, held, kind, moe, c in zip(unit_p, experts, self.unit_pattern,
+                                                 self.unit_moe, unit_c):
+                    if moe:
+                        p = dict(p, moe=dict(p["moe"], **held))
+                    x, nc, _ = _apply_block(p, x, cfg, kind, moe, mctx, positions=positions,
+                                            cache=c, cache_pos=pos, layer=layer)
                     ncs.append(nc)
                 return x, tuple(ncs)
 
             x, new_unit_state = jax.lax.scan(
-                unit_fn, x, (tuple(params["units"]), tuple(state["units"]))
+                unit_fn, x, (jnp.arange(self.n_units), units, tuple(state["units"]))
             )
             new_state["units"] = list(new_unit_state)
 

@@ -23,11 +23,18 @@ router scores all ``n_experts`` with the published top-k, and the local
 path computes exactly the part of the output that the held experts give
 (no capacity, nothing dropped) plus the shared experts; the absent
 experts' part is left out. Both paths compute their experts' part with
-:func:`_expert_ffn`. Spans: ``moe.route``, ``moe.experts``, ``moe.shared``.
+:func:`_expert_ffn`, except where a local layer has no more tokens than
+held experts (decode): it then reads only the held experts its tokens
+reach (:func:`_reached_experts`). Spans: ``moe.route``, ``moe.experts``,
+``moe.experts.reached``, ``moe.shared``; :func:`tally_paths` counts the
+path each traced lowering takes.
 """
 
 from __future__ import annotations
 
+import collections
+import contextlib
+import threading
 from functools import partial
 
 import jax
@@ -35,6 +42,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from .blocks import truncated_normal
+
+# The routed experts' stacked weights: (n_held, ...) in a layer's params,
+# (layers, n_held, ...) where a layer scan leaves them whole (``layer=``).
+EXPERT_KEYS = ("w_gate", "w_up", "w_down")
 
 # ---------------------------------------------------------------------------
 # Params
@@ -99,6 +110,34 @@ def _route(xf: jax.Array, router: jax.Array, m) -> tuple[jax.Array, jax.Array, j
     return ids, probs.astype(xf.dtype), aux
 
 
+class _PathTally(threading.local):
+    def __init__(self):
+        self.open: list[collections.Counter] = []
+
+
+_tally = _PathTally()
+
+
+@contextlib.contextmanager
+def tally_paths():
+    """Yields a Counter that counts, while open, each expert lowering
+    traced on this thread by the path it takes: ``reached``
+    (:func:`_reached_experts`) or ``_expert_ffn``'s ``impl`` (``ragged``,
+    ``batched``). A layer scan traces its body once, so it counts once
+    for all the layers it runs."""
+    counter: collections.Counter = collections.Counter()
+    _tally.open.append(counter)
+    try:
+        yield counter
+    finally:
+        _tally.open.remove(counter)
+
+
+def _took(path: str) -> None:
+    for counter in _tally.open:
+        counter[path] += 1
+
+
 def _expert_ffn(tokens: jax.Array, eids: jax.Array, p: dict, n_experts: int,
                 impl: str = "ragged", capacity_factor: float = 1.5) -> jax.Array:
     """Grouped expert GLU-FFN over tokens labelled by ``eids``.
@@ -106,14 +145,16 @@ def _expert_ffn(tokens: jax.Array, eids: jax.Array, p: dict, n_experts: int,
     ``eids == n_experts`` marks invalid/padding rows (zero output).
 
     impl="ragged": ``jax.lax.ragged_dot`` x3. Semantically exact (no
-    second-level dropping) but XLA's dense lowering multiplies FLOPs by
-    the local expert count — fine on backends with native grouped GEMM.
+    second-level dropping). It reads every expert of ``p`` whole, whatever
+    the group sizes (TPU's lowering does, and XLA's dense lowering also
+    multiplies FLOPs by the expert count): the grouped GEMM for many
+    tokens, and the reason decode takes :func:`_reached_experts`.
 
     impl="batched": capacity-bounded scatter into an (E, cap, d) buffer +
     three *batched* dense GEMMs. This is the MXU-shaped form: compiled
-    FLOPs = active-expert FLOPs x capacity_factor (EXPERIMENTS.md §Perf,
-    kimi-k2 iteration). Tokens beyond per-expert capacity are dropped
-    (standard GShard/Switch semantics)."""
+    FLOPs = active-expert FLOPs x capacity_factor. Tokens beyond
+    per-expert capacity are dropped (standard GShard/Switch semantics)."""
+    _took(impl)
     m, d = tokens.shape
     if impl == "ragged":
         safe_eids = jnp.minimum(eids, n_experts - 1)  # trash rows are zero tokens
@@ -140,8 +181,6 @@ def _expert_ffn(tokens: jax.Array, eids: jax.Array, p: dict, n_experts: int,
     buf = buf[:n_experts]
     gate = jnp.einsum("ecd,edf->ecf", buf, p["w_gate"])
     up = jnp.einsum("ecd,edf->ecf", buf, p["w_up"])
-    # (kimi §Perf iteration 3 tried bf16 GLU here — refuted: the dominant
-    # converts are the attention chunk accumulators, not this path)
     h = jax.nn.silu(gate.astype(jnp.float32)).astype(tokens.dtype) * up.astype(tokens.dtype)
     out = jnp.einsum("ecf,efd->ecd", h, p["w_down"]).astype(tokens.dtype)
     gathered = out[jnp.minimum(eid_s, n_experts - 1), jnp.minimum(pos, cap - 1)]
@@ -154,29 +193,101 @@ def _expert_ffn(tokens: jax.Array, eids: jax.Array, p: dict, n_experts: int,
 # ---------------------------------------------------------------------------
 
 
-def moe_local(p: dict, x: jax.Array, cfg) -> tuple[jax.Array, jax.Array]:
+def _at_layer(p: dict, layer) -> dict:
+    """``p`` with its routed experts' stacks cut to layer ``layer`` (None:
+    they are one layer's already)."""
+    if layer is None:
+        return p
+    return dict(p, **{k: p[k][layer] for k in EXPERT_KEYS})
+
+
+def _grouped_experts(xf: jax.Array, ids: jax.Array, probs: jax.Array, p: dict, m) -> jax.Array:
+    """The held experts' part of each token's output through
+    :func:`_expert_ffn` (``m.expert_impl``, ``ragged`` by default). xf:
+    (n, d); ids, probs: (n, k). An assignment to an expert not held
+    becomes a zero token under the trash id ``n_held`` and weighs nothing
+    in the combine."""
+    n, k = ids.shape
+    tok_idx = jnp.repeat(jnp.arange(n), k)
+    local = ids.reshape(-1) - m.first_held
+    held = (local >= 0) & (local < m.n_held)
+    out_flat = _expert_ffn(
+        jnp.where(held[:, None], xf[tok_idx], 0), jnp.where(held, local, m.n_held), p, m.n_held,
+        impl=getattr(m, "expert_impl", "ragged"),
+        capacity_factor=m.capacity_factor + 0.25,
+    )
+    w = jnp.where(held, probs.reshape(-1), 0)
+    return jnp.zeros_like(xf).at[tok_idx].add(out_flat * w[:, None])
+
+
+def _reached_experts(xf: jax.Array, ids: jax.Array, probs: jax.Array, p: dict, m,
+                     layer=None) -> jax.Array:
+    """What :func:`_grouped_experts` gives, reading only the held experts
+    that the tokens reach. xf: (n, d); ids, probs: (n, k).
+
+    A ``while_loop`` runs once for each distinct held expert reached (c of
+    them, at most min(n * k, n_held)), in the order the assignments first
+    reach them: at n = 1 that is the token's top-k order, the order of the
+    grouped path's combine. Each iteration takes the expert's three
+    matrices by a dynamic index; with ``layer`` (the stacks left whole by a
+    layer scan) the index reaches into the stack, so the program reads c
+    experts, and no layer's n_held. It computes the expert's GLU for all n
+    rows (SiLU in float32, then ``xf``'s dtype, as ``_expert_ffn``) and
+    adds ``prob * out``, in ``xf``'s dtype, to the rows that route to it.
+    The loop's trip count is data, so reverse-mode differentiation does
+    not go through it: the path is for inference."""
+    n, k = ids.shape
+    local = ids.reshape(-1) - m.first_held
+    slot = jnp.where((local >= 0) & (local < m.n_held), local, m.n_held)  # (n * k,)
+    first = jnp.full((m.n_held + 1,), n * k).at[slot].min(jnp.arange(n * k))[: m.n_held]
+    order = jnp.argsort(first)  # reached experts first, unreached (first = n * k) last
+    slot = slot.reshape(n, k)
+
+    def body(j, y):
+        e = order[j]
+        idx = e if layer is None else (layer, e)
+        w_gate, w_up, w_down = (p[key][idx] for key in EXPERT_KEYS)
+        gate, up = xf @ w_gate, xf @ w_up
+        h = jax.nn.silu(gate.astype(jnp.float32)).astype(xf.dtype) * up.astype(xf.dtype)
+        out = (h @ w_down).astype(xf.dtype)
+        sel = slot == e  # (n, k): a token's k experts differ, so one True at most
+        w = jnp.where(sel, probs, 0).sum(-1, keepdims=True)
+        return y + jnp.where(sel.any(-1, keepdims=True), out * w, 0)
+
+    return jax.lax.fori_loop(0, (first < n * k).sum(), body, jnp.zeros_like(xf))
+
+
+def moe_local(p: dict, x: jax.Array, cfg, layer=None) -> tuple[jax.Array, jax.Array]:
     """Single-shard routed-experts forward of the held experts. x: (b, s, d).
 
     Routes over all ``n_experts``; an assignment to an expert this layer
-    does not hold becomes a zero token under the trash id ``n_held`` and
-    weighs nothing in the combine."""
+    does not hold weighs nothing in the combine. ``layer``: where ``p``'s
+    routed experts are the whole stacks of a layer scan, this layer's
+    index in them.
+
+    The path follows the token count n = b * s, by the bytes each reads.
+    ``ragged`` reads all n_held experts whole (and a layer scan's slice of
+    them cannot fuse into its lowering, so XLA copies it first);
+    :func:`_reached_experts` reads the c distinct held experts reached, at
+    most min(n * k, n_held), and computes each for all n rows. With n <=
+    n_held it takes the reached path: batch-1 decode of a top-6 of 64
+    router reaches 6 * n_held / 64 held experts on average. Past n_held
+    tokens nearly every held expert is reached (n_held * (1 - (1 -
+    k / n_experts)^n) on average), the reads are the same, and n rows per
+    expert cost more than the grouped GEMM's n * k rows in all, so prefill
+    and training take :func:`_grouped_experts`."""
     m = cfg.moe
     b, s, d = x.shape
     xf = x.reshape(-1, d)
     with jax.named_scope("moe.route"):
         ids, probs, aux = _route(xf, p["router"], m)
-    with jax.named_scope("moe.experts"):
-        n, k = ids.shape
-        tok_idx = jnp.repeat(jnp.arange(n), k)
-        local = ids.reshape(-1) - m.first_held
-        held = (local >= 0) & (local < m.n_held)
-        out_flat = _expert_ffn(
-            jnp.where(held[:, None], xf[tok_idx], 0), jnp.where(held, local, m.n_held), p, m.n_held,
-            impl=getattr(m, "expert_impl", "ragged"),
-            capacity_factor=m.capacity_factor + 0.25,
-        )
-        w = jnp.where(held, probs.reshape(-1), 0)
-        y = jnp.zeros_like(xf).at[tok_idx].add(out_flat * w[:, None])
+    if xf.shape[0] <= m.n_held:
+        _took("reached")
+        with jax.named_scope("moe.experts.reached"):
+            y = _reached_experts(xf, ids, probs, p, m, layer)
+    else:
+        with jax.named_scope("moe.experts"):
+            y = _grouped_experts(xf, ids, probs, _at_layer(p, layer), m)
     return y.reshape(b, s, d), aux
 
 
@@ -271,8 +382,11 @@ def moe_ep(p: dict, x: jax.Array, cfg, mesh, data_axes: tuple[str, ...], model_a
 
 
 def apply_moe(
-    p: dict, x: jax.Array, cfg, mesh=None, data_axes: tuple[str, ...] = (), model_axis: str = ""
+    p: dict, x: jax.Array, cfg, mesh=None, data_axes: tuple[str, ...] = (), model_axis: str = "",
+    layer=None,
 ) -> tuple[jax.Array, jax.Array]:
+    """Shared plus routed experts. ``layer``: where ``p``'s routed experts
+    are the whole stacks of a layer scan, this layer's index in them."""
     m = cfg.moe
     use_ep = (
         mesh is not None
@@ -282,9 +396,9 @@ def apply_moe(
         and m.n_experts % mesh.shape[model_axis] == 0
     )
     if use_ep:
-        y, aux = moe_ep(p, x, cfg, mesh, data_axes, model_axis)
+        y, aux = moe_ep(_at_layer(p, layer), x, cfg, mesh, data_axes, model_axis)
     else:
-        y, aux = moe_local(p, x, cfg)
+        y, aux = moe_local(p, x, cfg, layer)
     if m.n_shared:
         with jax.named_scope("moe.shared"):
             sp = p["shared"]

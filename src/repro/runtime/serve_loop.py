@@ -39,6 +39,7 @@ row program arrives as an argument; anything it needs it carries.
 
 from __future__ import annotations
 
+import functools
 import threading
 import time
 from collections import OrderedDict, deque
@@ -49,6 +50,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..models.moe import tally_paths
 from ..spans import span
 
 PAD_ID = 0
@@ -173,7 +175,11 @@ class ServeStats:
     compiled or loaded from the compilation cache while a call ran, and
     their time. ``program_builds`` and ``program_reuses``: per call,
     whether the model's step and prefill programs had to be made or were
-    found on the model. ``state_bytes``: the bytes of one slot's decode
+    found on the model. ``moe_paths``: per program (``step``,
+    ``prefill``), the expert paths its MoE layers took where the model's
+    programs were traced (``reached``, ``ragged``; see
+    :func:`repro.models.moe.tally_paths`), empty for a model without MoE.
+    ``state_bytes``: the bytes of one slot's decode
     state (its cache), counted from the state's shapes when the slots'
     states are made. Per request (by uid): ``latency_s`` from the admission
     offer to the final token, ``first_token_s`` from the offer to the
@@ -193,6 +199,7 @@ class ServeStats:
     compile_s: float = 0.0
     program_builds: int = 0
     program_reuses: int = 0
+    moe_paths: dict[str, tuple[str, ...]] = field(default_factory=dict)
     state_bytes: int = 0
     latency_s: dict[int, float] = field(default_factory=dict)
     first_token_s: dict[int, float] = field(default_factory=dict)
@@ -238,6 +245,20 @@ _compile_ledger = _CompileLedger()
 jax.monitoring.register_event_time_span_listener(_compile_ledger.event)
 
 
+def _tallied(fn, paths: set):
+    """``fn``, adding the MoE expert paths each of its traces takes to
+    ``paths`` (the name stays ``fn``'s, and so does the program's)."""
+
+    @functools.wraps(fn)
+    def traced(*args):
+        with tally_paths() as tally:
+            out = fn(*args)
+        paths.update(tally)
+        return out
+
+    return traced
+
+
 def _programs(model, stats: ServeStats | None):
     """The model's jitted decode step and prefill, made on its first serve
     and kept on the instance, so every later call on it reuses them and
@@ -245,11 +266,14 @@ def _programs(model, stats: ServeStats | None):
     ``max_seq`` and ``cache_dtype`` it has seen. As an attribute they live
     and die with the model (their closures hold it, so nothing outside the
     model may hold them). ``params`` stay arguments, so one program serves
-    any weights of the same shapes."""
+    any weights of the same shapes. The third item gathers, per program,
+    the MoE expert paths its traces took."""
     programs = getattr(model, "_serve_programs", None)
     built = programs is None
     if built:
-        programs = jax.jit(make_serve_step(model)), jax.jit(model.decode_step)
+        paths = {"step": set(), "prefill": set()}
+        programs = (jax.jit(_tallied(make_serve_step(model), paths["step"])),
+                    jax.jit(_tallied(model.decode_step, paths["prefill"])), paths)
         model._serve_programs = programs
     if stats is not None:
         stats.program_builds += built
@@ -278,7 +302,7 @@ def _continuous_decode(
     span whose time goes to ``stats.prefill_s`` / ``stats.decode_s`` when
     ``stats`` is given; ``on_token(uid, index)`` is called as each token
     reaches the host (index 0 is the prefill's)."""
-    step, prefill = _programs(model, stats)
+    step, prefill, paths = _programs(model, stats)
 
     # one independent state per slot (batch=1) so refills don't disturb others
     states = [model.init_decode_state(1, max_seq, cache_dtype) for _ in range(slots)]
@@ -329,6 +353,8 @@ def _continuous_decode(
                 on_token(a["uid"], len(a["out"]))
             a["out"].append(last_tok[s])
             a["pos"] += 1
+    if stats is not None:
+        stats.moe_paths = {name: tuple(sorted(took)) for name, took in paths.items() if took}
 
 
 def serve_requests(

@@ -14,6 +14,8 @@ compiles live in this one file for the same reason.
 
 from __future__ import annotations
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -153,3 +155,45 @@ def test_seq2seq_train_step_compiles_at_config_widths(one_chip):
         "decoder_tokens": _spec(one_chip, (32, CONFIG.max_title_len), jnp.int32),
     }
     _compile(step, on_chip(params), on_chip(jax.eval_shape(opt.init, params)), batch)
+
+
+def _whole_held_experts(text: str) -> list[str]:
+    """The instructions whose value is one layer's 8 held experts whole:
+    a (8, 2048, 1408) or (8, 1408, 2048) slice of DeepSeek-V2-Lite's
+    routed experts, as a copy, fusion or slice would make it."""
+    shape = re.compile(r"= bf16\[(1,)?8,(2048,1408|1408,2048)\]")
+    return [line.strip() for line in text.splitlines() if shape.search(line)]
+
+
+@pytest.mark.parametrize("tokens, path", [(1, "reached"), (64, "ragged")])
+def test_dsv2lite_decode_reads_only_the_reached_experts(one_chip, tokens, path):
+    """The ep8 share's serve step (one token) compiles with no op that
+    copies or reads a layer's 8 held experts whole: the reached path
+    indexes each expert in the scan's whole stack and the slice fuses into
+    its matrix-vector products. A 64-token prefill keeps the ragged
+    grouped GEMM, which takes the layer's slice whole."""
+    from repro.configs import get
+    from repro.models import moe as MOE
+    from repro.models.lm import LM
+    from repro.runtime.serve_loop import make_serve_step
+
+    model = LM(get("deepseek_v2_lite_ep8"), remat=False, dtype=jnp.bfloat16)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: _spec(one_chip, x.shape, x.dtype), tree)
+
+    params = on_chip(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    state = on_chip(jax.eval_shape(lambda: model.init_decode_state(1, 256, jnp.bfloat16)))
+    fn = make_serve_step(model) if tokens == 1 else model.decode_step
+    with MOE.tally_paths() as took:
+        compiled = _compile(fn, params, _spec(one_chip, (1, tokens), jnp.int32), state,
+                            _spec(one_chip, (), jnp.int32))
+    assert set(took) == {path}
+    text = compiled.as_text()
+    whole = _whole_held_experts(text)
+    if path == "reached":
+        assert text.startswith("HloModule jit_serve_step")
+        assert not whole, whole[:3]
+        assert "ragged" not in text
+    else:
+        assert whole and "ragged" in text
